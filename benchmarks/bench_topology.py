@@ -163,6 +163,7 @@ FUSED_PAYLOADS = (1 << 16, 1 << 17)
 def _run_fused_child():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
     env["PYTHONPATH"] = os.pathsep.join([REPO, os.path.join(REPO, "src")])
     r = subprocess.run(
         [
@@ -183,6 +184,7 @@ def _run_fused_child():
 def run():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"  # emulated host devices, never the chip
     # repo root (for benchmarks.common) + src (for repro)
     env["PYTHONPATH"] = os.pathsep.join([REPO, os.path.join(REPO, "src")])
     r = subprocess.run(

@@ -136,7 +136,12 @@ def lcc_encode_collective(mesh, axis: str, plan: LCCPlan, **kw):
     Lagrange generator, communication = ppermute rounds on ``axis`` (size N)
     — the prepare-and-shoot ScheduleIR executed through
     ``dist.collectives.ir_encode_jit``. Input rows K..N−1 must be the zero
-    padding (:func:`lcc_pad`)."""
+    padding (:func:`lcc_pad`). The input may live anywhere (e.g. on the one
+    device that serves the model): row j is first placed on the axis's
+    device j, as the encode's sharding requires."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
     from repro.dist.collectives import ps_encode_jit
 
     K_axis = int(mesh.shape[axis])
@@ -145,7 +150,8 @@ def lcc_encode_collective(mesh, axis: str, plan: LCCPlan, **kw):
             f"mesh axis {axis!r} has {K_axis} devices, need N={plan.N}"
         )
     fn, _ = ps_encode_jit(mesh, axis, lcc_generator(plan), p=plan.p, q=plan.q, **kw)
-    return fn
+    rows = NamedSharding(mesh, PartitionSpec(axis))
+    return lambda xp: fn(jax.device_put(xp, rows))
 
 
 def _validate_responders(plan: LCCPlan, responders) -> list[int]:

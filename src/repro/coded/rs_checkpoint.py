@@ -23,6 +23,7 @@ elements < 2^16 < q), encoded, and reassembled — no rounding anywhere.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -52,7 +53,11 @@ class LimbMeta:
 
 
 def state_to_limbs(state) -> tuple[jnp.ndarray, LimbMeta]:
-    """Pytree → (S,) uint32 array of 16-bit limbs (canonical mod-q elements)."""
+    """Pytree → (S,) uint32 array of 16-bit limbs (canonical mod-q elements).
+
+    A 2-byte leaf gives one limb per element (its bit pattern); a 4-byte
+    leaf gives all its low halves, then all its high halves; a 1-byte leaf
+    packs two bytes per limb, little-endian."""
     leaves, treedef = jax.tree.flatten(state)
     parts = []
     shapes, dtypes, sizes = [], [], []
@@ -60,37 +65,64 @@ def state_to_limbs(state) -> tuple[jnp.ndarray, LimbMeta]:
         arr = jnp.asarray(leaf)
         shapes.append(arr.shape)
         dtypes.append(arr.dtype)
-        if arr.dtype == jnp.bool_:  # bitcast can't take bool directly
-            arr = arr.astype(jnp.uint8)
-        u8 = jax.lax.bitcast_convert_type(
-            arr.reshape(-1), jnp.uint8
-        ).reshape(-1)
-        if u8.size % 2:
-            u8 = jnp.pad(u8, (0, 1))
-        u16 = u8[0::2].astype(jnp.uint32) | (u8[1::2].astype(jnp.uint32) << 8)
+        u16 = _leaf_to_limbs(arr)
         sizes.append(int(u16.size))
         parts.append(u16)
     limbs = jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.uint32)
     return limbs, LimbMeta(treedef, shapes, dtypes, sizes, int(limbs.size))
 
 
+# Each leaf is converted by one jitted program and never through an array
+# whose last dimension is 2: a TPU pads that dimension to 128 lanes, and a
+# bf16 cache bitcast to (N, 2) bytes is then 64x its size.
+
+
+@jax.jit
+def _leaf_to_limbs(arr):
+    if arr.dtype == jnp.bool_:  # bitcast can't take bool directly
+        arr = arr.astype(jnp.uint8)
+    flat = arr.reshape(-1)
+    size = arr.dtype.itemsize
+    if size == 2:
+        return jax.lax.bitcast_convert_type(flat, jnp.uint16).astype(jnp.uint32)
+    if size == 4:
+        w = jax.lax.bitcast_convert_type(flat, jnp.uint32)
+        return jnp.concatenate([w & 0xFFFF, w >> 16])
+    if size != 1:
+        raise TypeError(f"no 16-bit limb layout for {arr.dtype}")
+    u8 = jax.lax.bitcast_convert_type(flat, jnp.uint8).astype(jnp.uint32)
+    if u8.size % 2:
+        u8 = jnp.pad(u8, (0, 1))
+    return u8[0::2] | (u8[1::2] << 8)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _leaf_from_limbs(u16, shape, dtype):
+    n = int(np.prod(shape, dtype=np.int64))
+    size = dtype.itemsize
+    if size == 2:
+        flat = jax.lax.bitcast_convert_type(u16.astype(jnp.uint16), dtype)
+    elif size == 4:
+        flat = jax.lax.bitcast_convert_type(u16[:n] | (u16[n:] << 16), dtype)
+    else:
+        u8 = jnp.stack([u16 & 0xFF, u16 >> 8], axis=1).reshape(-1)[:n]
+        u8 = u8.astype(jnp.uint8)
+        flat = u8.astype(jnp.bool_) if dtype == jnp.bool_ else (
+            jax.lax.bitcast_convert_type(u8, dtype)
+        )
+    return flat.reshape(shape)
+
+
 def limbs_to_state(limbs: jnp.ndarray, meta: LimbMeta):
     out = []
     off = 0
     for shape, dtype, size in zip(meta.shapes, meta.dtypes, meta.sizes_u16):
-        u16 = limbs[off : off + size]
+        out.append(
+            _leaf_from_limbs(
+                limbs[off : off + size], shape=tuple(shape), dtype=np.dtype(dtype)
+            )
+        )
         off += size
-        u8 = jnp.stack(
-            [u16 & 0xFF, (u16 >> 8) & 0xFF], axis=1
-        ).reshape(-1).astype(jnp.uint8)
-        nbytes = int(np.prod(shape, dtype=np.int64)) * jnp.dtype(dtype).itemsize
-        u8 = u8[:nbytes]
-        if jnp.dtype(dtype) == jnp.bool_:
-            arr = u8.astype(jnp.bool_).reshape(shape)
-        else:
-            itemsize = jnp.dtype(dtype).itemsize
-            arr = jax.lax.bitcast_convert_type(u8.reshape(-1, itemsize), dtype).reshape(shape)
-        out.append(arr)
     return jax.tree.unflatten(meta.treedef, out)
 
 

@@ -1,33 +1,15 @@
-"""shard_map across jax versions.
+"""The one ``shard_map`` call every executor uses.
 
-* jax < 0.6: ``jax.experimental.shard_map.shard_map`` with ``check_rep``;
-* jax >= 0.6: public ``jax.shard_map`` where the kwarg became ``check_vma``
-  (and older spellings were removed).
-
-Replication/varying-manual-axes checking is disabled in both: the rep
-checker in several jax versions rejects valid ppermute/psum mixtures inside
-unrolled collective loops.
+Varying-manual-axes checking is off: the checker rejects valid
+ppermute/psum mixtures inside the unrolled collective loops.
 """
 
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.6 moved shard_map out of experimental
-    from jax import shard_map as _raw_shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-_PARAMS = inspect.signature(_raw_shard_map).parameters
-if "check_vma" in _PARAMS:
-    _CHECK_KWARGS = {"check_vma": False}
-elif "check_rep" in _PARAMS:
-    _CHECK_KWARGS = {"check_rep": False}
-else:  # pragma: no cover - future-proofing
-    _CHECK_KWARGS = {}
+import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    return _raw_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_CHECK_KWARGS
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

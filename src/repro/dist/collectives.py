@@ -87,12 +87,19 @@ def _bcast(coef, npay: int):
 KERNEL_MODES = ("jnp", "fused", "pallas")
 
 
-def _resolve_kernels(kernels: str | None) -> str:
-    """LocalOp lowering mode: ``None`` auto-selects the Pallas kernels on
-    TPU and the batched-jnp fused lowering elsewhere; ``"jnp"`` is the
+def _mesh_platform(mesh) -> str:
+    """Platform of the devices the program is compiled for — the mesh's,
+    not the process default backend (a TPU mesh built from a CPU-default
+    process, or a described topology, still gets the TPU lowering)."""
+    return mesh.devices.flat[0].platform
+
+
+def _resolve_kernels(kernels: str | None, platform: str) -> str:
+    """LocalOp lowering mode: ``None`` auto-selects the Pallas kernels on a
+    TPU mesh and the batched-jnp fused lowering elsewhere; ``"jnp"`` is the
     legacy per-coefficient loop kept as the flagged fallback."""
     if kernels is None:
-        return "pallas" if jax.default_backend() == "tpu" else "fused"
+        return "pallas" if platform == "tpu" else "fused"
     if kernels not in KERNEL_MODES:
         raise ValueError(f"kernels must be one of {KERNEL_MODES} or None, got {kernels!r}")
     return kernels
@@ -190,15 +197,17 @@ def ir_encode_jit(
     ppermute — the traced ``round[r]`` span carries ``overlap`` attrs.
 
     ``kernels`` selects the LocalOp lowering: ``"pallas"`` routes general
-    rows through ``gf_matmul``/``butterfly_mac`` (``interpret=`` on non-TPU
-    backends), ``"fused"`` uses ONE batched Shoup contraction per op,
-    ``"jnp"`` keeps the legacy per-coefficient loop, and ``None`` picks
-    ``"pallas"`` on TPU / ``"fused"`` elsewhere. All three are bit-exact
-    (differential suite: tests/test_fused_encode.py).
+    rows through ``gf_matmul``/``butterfly_mac`` (compiled on a TPU mesh,
+    interpreted on any other), ``"fused"`` uses ONE batched Shoup
+    contraction per op, ``"jnp"`` keeps the legacy per-coefficient loop,
+    and ``None`` picks ``"pallas"`` when the mesh's devices are TPUs and
+    ``"fused"`` otherwise. All three are bit-exact (differential suite:
+    tests/test_fused_encode.py).
     """
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    kernels = _resolve_kernels(kernels)
-    pallas_interp = jax.default_backend() != "tpu"
+    platform = _mesh_platform(mesh)
+    kernels = _resolve_kernels(kernels, platform)
+    pallas_interp = platform != "tpu"
     K = 1
     for ax in axes:
         K *= int(mesh.shape[ax])

@@ -60,7 +60,7 @@ def butterfly_mac_pallas(
     q: int,
     block_b: int = 256,
     block_p: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     radix, B, P = parts.shape
     assert B % block_b == 0 and P % block_p == 0, (parts.shape, block_b, block_p)

@@ -22,7 +22,7 @@ def butterfly_mac(
     tw_sh: jnp.ndarray,  # (B, radix) uint32
     *,
     q: int,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """out[b, ...] = Σ_ρ tw[b, ρ] · parts[ρ, b, ...] (mod q); pads/reshapes
     payload to the kernel's 2D tiling."""

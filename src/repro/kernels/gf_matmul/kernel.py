@@ -1,23 +1,25 @@
-"""Pallas TPU kernel: GF(q) modular matmul via byte-limb MXU decomposition.
+"""Pallas TPU kernel: GF(q) modular matmul via 7-bit-limb MXU decomposition.
 
 TPU adaptation (DESIGN §3/§7): the MXU has no 64-bit integer path, so a
 direct ``(a*b) % q`` contraction cannot use it. Instead each uint32 operand
-is split into four 8-bit limbs; the product becomes
+is split into five 7-bit limbs (35 bits ≥ 32); the product becomes
 
-    A·B = Σ_{c=0}^{6} D_c · 2^{8c},   D_c = Σ_{i+j=c} A_i · B_j
+    A·B = Σ_{c=0}^{8} D_c · 2^{7c},   D_c = Σ_{i+j=c} A_i · B_j
 
-where each ``A_i · B_j`` is a uint8×uint8→int32 matmul — exactly the MXU's
-native int8 mode (bounded: 255²·block_k < 2^31 for block_k ≤ 32768, so the
-int32 accumulation is exact). The seven class sums D_c are then folded
-modulo q on the VPU once per output tile: Barrett-reduce D_c and Shoup-
-multiply by the constant 2^{8c} mod q.
+where each ``A_i · B_j`` is an int8×int8→int32 matmul — the MXU's native
+integer mode. The MXU multiplies int8 as SIGNED, so a limb must stay below
+2^7: 8-bit limbs (four per word) came back wrong on a v5e for any byte
+≥ 128. A class sum adds at most five products, 5·127²·block_k < 2^31 for
+block_k ≤ 16384, so the int32 accumulation is exact. The nine class sums
+D_c are then folded modulo q on the VPU once per output tile:
+Barrett-reduce D_c and Shoup-multiply by the constant 2^{7c} mod q.
 
 Grid: (M/bm, N/bn, K/bk); the K dimension accumulates into the uint32
 output block (canonical mod-q residues) across grid steps.
 
 VMEM per step (defaults bm=bn=128, bk=512):
     A block 128·512·4 B = 256 KiB, B block 512·128·4 B = 256 KiB,
-    out 64 KiB, limb temporaries ≈ 8·(block bytes)/4 — comfortably < 16 MiB.
+    out 64 KiB, limb temporaries ≈ 10·(block bytes)/4 — comfortably < 16 MiB.
 MXU alignment: bm, bn multiples of 128; bk multiple of 8 (≥ 128 preferred).
 """
 
@@ -31,13 +33,15 @@ from jax.experimental import pallas as pl
 
 from repro.core.field import shoup_precompute
 
-_NLIMB = 4
-_NCLASS = 2 * _NLIMB - 1
+_LIMB_BITS = 7
+_LIMB_MASK = (1 << _LIMB_BITS) - 1
+_NLIMB = -(-32 // _LIMB_BITS)  # 5
+_NCLASS = 2 * _NLIMB - 1  # 9
 
 
 def _fold_constants(q: int):
-    """(2^{8c} mod q, shoup(2^{8c} mod q)) for c = 0..6."""
-    rs = [(1 << (8 * c)) % q for c in range(_NCLASS)]
+    """(2^{7c} mod q, shoup(2^{7c} mod q)) for c = 0..8."""
+    rs = [(1 << (_LIMB_BITS * c)) % q for c in range(_NCLASS)]
     pres = [int(shoup_precompute(r, q)) for r in rs]
     return rs, pres
 
@@ -83,6 +87,11 @@ def _shoup(a_u32, c: int, c_pre: int, q: int):
     return jnp.where(r >= q, r - jnp.uint32(q), r)
 
 
+def _limb(x_u32, i: int):
+    """Limb i of a uint32 block: bits [7i, 7i+7), as a nonnegative int8."""
+    return ((x_u32 >> (_LIMB_BITS * i)) & _LIMB_MASK).astype(jnp.int8)
+
+
 def _gf_matmul_kernel(a_ref, b_ref, out_ref, *, q: int, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -90,8 +99,8 @@ def _gf_matmul_kernel(a_ref, b_ref, out_ref, *, q: int, k_steps: int):
 
     a = a_ref[...]  # (bm, bk) uint32
     b = b_ref[...]  # (bk, bn) uint32
-    a_limbs = [((a >> (8 * i)) & 0xFF).astype(jnp.uint8) for i in range(_NLIMB)]
-    b_limbs = [((b >> (8 * j)) & 0xFF).astype(jnp.uint8) for j in range(_NLIMB)]
+    a_limbs = [_limb(a, i) for i in range(_NLIMB)]
+    b_limbs = [_limb(b, j) for j in range(_NLIMB)]
 
     rs, pres = _fold_constants(q)
     folded = None
@@ -99,7 +108,7 @@ def _gf_matmul_kernel(a_ref, b_ref, out_ref, *, q: int, k_steps: int):
         d = None
         for i in range(max(0, c - _NLIMB + 1), min(_NLIMB, c + 1)):
             j = c - i
-            # uint8 x uint8 -> int32: the MXU-native integer mode
+            # int8 x int8 -> int32: the MXU-native integer mode
             prod = jax.lax.dot_general(
                 a_limbs[i],
                 b_limbs[j],
@@ -130,7 +139,7 @@ def gf_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """C = (A @ B) mod q. a: (M, K) uint32, b: (K, N) uint32, shapes must be
     multiples of the block sizes (ops.py pads)."""
@@ -142,7 +151,7 @@ def gf_matmul_pallas(
         b.shape,
         (block_m, block_n, block_k),
     )
-    assert block_k <= 32768, "int32 limb accumulation bound"
+    assert block_k <= 16384, "int32 limb accumulation bound"
     k_steps = K // block_k
     grid = (M // block_m, N // block_n, k_steps)
     return pl.pallas_call(

@@ -37,7 +37,7 @@ def gf_matmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """C = (A @ B) mod q for arbitrary (M, K) x (K, N) uint32 inputs.
 
@@ -68,7 +68,7 @@ def _round_up(x: int, m: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("q", "interpret"))
 def gf_matmul_batched(
-    a: jnp.ndarray, b: jnp.ndarray, *, q: int, interpret: bool = True
+    a: jnp.ndarray, b: jnp.ndarray, *, q: int, interpret: bool = False
 ) -> jnp.ndarray:
     """Batched C[i] = (A[i] @ B[i]) mod q via vmap over the Pallas kernel.
 
@@ -96,7 +96,7 @@ def gf_matmul_reference(a, b, *, q):
     return gf_matmul_ref(a, b, q)
 
 
-def encode_direct(x: jnp.ndarray, G: jnp.ndarray | np.ndarray, *, q: int, interpret: bool = True):
+def encode_direct(x: jnp.ndarray, G: jnp.ndarray | np.ndarray, *, q: int, interpret: bool = False):
     """Direct (non-collective) encode baseline: X @ G mod q via the kernel.
 
     x: (S, K) payload-major state limbs; G: (K, N) generator. This is the

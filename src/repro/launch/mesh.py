@@ -16,6 +16,7 @@ mirror of :func:`make_production_mesh` (no devices needed to price it).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -55,10 +56,6 @@ def topology_for_mesh(mesh, axes):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh with Auto axis types where the installed jax supports
-    them (>= 0.5); older versions have no axis_types kwarg and every axis is
-    implicitly Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA). A
+    mesh smaller than the host takes the first ``prod(shape)`` devices."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

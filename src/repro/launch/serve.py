@@ -27,6 +27,7 @@ import jax
 
 from repro.configs import get, smoke_config
 from repro.configs.base import ShapeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.profiles import BASELINE, rules_for
 from repro.models import build_model
@@ -38,6 +39,20 @@ from repro.serve import (
     Request,
 )
 from repro.train import latest_step, param_shardings, restore_checkpoint
+
+
+def build_serving(cfg, mesh, max_len: int, *, ckpt=None):
+    """``(model, params, rules)`` for serving ``cfg`` on ``mesh``: sharding
+    rules for a ``max_len`` decode, params initialised from key 0 straight
+    into their shardings, then restored from ``ckpt`` when it holds a step."""
+    rules = rules_for(cfg, ShapeSpec("cli", "decode", max_len, 1), BASELINE)
+    model = build_model(cfg)
+    ps = param_shardings(model, mesh, rules)
+    params = jax.jit(model.init, out_shardings=ps)(jax.random.key(0))
+    if ckpt and latest_step(ckpt) is not None:
+        like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+        params, _ = restore_checkpoint(ckpt, like, shardings=ps)
+    return model, params, rules
 
 
 def main():
@@ -65,17 +80,11 @@ def main():
     if args.kill and args.coded is None:
         ap.error("--kill requires --coded K,R")
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((d, m), ("data", "model"))
-    shape = ShapeSpec("cli", "decode", args.max_len, 1)
-    rules = rules_for(cfg, shape, BASELINE)
-    model = build_model(cfg)
-    ps = param_shardings(model, mesh, rules)
-    params = jax.jit(model.init, out_shardings=ps)(jax.random.key(0))
-    if args.ckpt and latest_step(args.ckpt) is not None:
-        like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
-        params, _ = restore_checkpoint(args.ckpt, like, shardings=ps)
+    model, params, rules = build_serving(cfg, mesh, args.max_len, ckpt=args.ckpt)
 
     prompts = [[int(t) for t in p.split(",") if t] for p in args.prompts.split(";")]
     use_continuous = args.engine == "continuous" and model.supports_prefill

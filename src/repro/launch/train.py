@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import get, smoke_config
 from repro.dist.sharding import named_sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.profiles import BASELINE, OPT, rules_for
 from repro.configs.base import ShapeSpec
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--coded-k", type=int, default=8)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
     if d * m > len(jax.devices()):

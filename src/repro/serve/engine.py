@@ -386,6 +386,22 @@ class ContinuousEngine:
         return jax.jit(prefill, donate_argnums=(1, 2))
 
     # -- serve loop ---------------------------------------------------------
+    def init_state(self):
+        """``(cache, state)`` with every slot free: what a serve starts
+        from, and the argument schema of the decode tick and prefill."""
+        S, G = self.n_slots, self.max_new_tokens
+        cache = self.model.init_cache(S, self.max_len)
+        state = {
+            "last_tok": jnp.zeros((S,), jnp.int32),
+            "pos": jnp.zeros((S,), jnp.int32),
+            "active": jnp.zeros((S,), jnp.bool_),
+            "gen_buf": jnp.zeros((S, G), jnp.int32),
+            "gen_count": jnp.zeros((S,), jnp.int32),
+            "max_gen": jnp.zeros((S,), jnp.int32),
+            "rng": jnp.zeros((S, 2), jnp.uint32),
+        }
+        return cache, state
+
     def _validate(self, req: Request) -> None:
         plen = len(req.prompt)
         bucket_for(plen, self.buckets)  # raises if no bucket covers it
@@ -435,17 +451,8 @@ class ContinuousEngine:
         for r in ordered:
             self._validate(r)
             sched.submit(r)
-        S, G = self.n_slots, self.max_new_tokens
-        cache = self.model.init_cache(S, self.max_len)
-        state = {
-            "last_tok": jnp.zeros((S,), jnp.int32),
-            "pos": jnp.zeros((S,), jnp.int32),
-            "active": jnp.zeros((S,), jnp.bool_),
-            "gen_buf": jnp.zeros((S, G), jnp.int32),
-            "gen_count": jnp.zeros((S,), jnp.int32),
-            "max_gen": jnp.zeros((S,), jnp.int32),
-            "rng": jnp.zeros((S, 2), jnp.uint32),
-        }
+        S = self.n_slots
+        cache, state = self.init_state()
         base_key = jax.random.key(seed)
         temp = jnp.float32(temperature)
         eos = jnp.int32(-1 if eos_id is None else eos_id)

@@ -35,9 +35,20 @@ def test_limb_bitcast_roundtrip():
         "m": jnp.asarray(np.random.default_rng(1).normal(size=(11,)).astype(np.float32)),
         "b16": jnp.asarray(np.random.default_rng(2).normal(size=(3, 3)), dtype=jnp.bfloat16),
         "i": jnp.arange(9, dtype=jnp.int32),
+        "flag": jnp.asarray([True, False, True]),  # odd byte count: padded limb
+        "i8": jnp.asarray([-128, -1, 0, 7, 127], dtype=jnp.int8),
+        "s": jnp.float32(3.5),
     }
     limbs, meta = state_to_limbs(state)
     assert limbs.dtype == jnp.uint32 and int(limbs.max()) < 2**16
+    # limbs are each leaf's bytes in memory order, two per limb, little-endian
+    n_b16 = 0
+    for leaf in jax.tree.leaves(state)[:jax.tree.leaves(state).index(state["b16"])]:
+        n_b16 += -(-leaf.size * leaf.dtype.itemsize // 2)
+    np.testing.assert_array_equal(
+        np.asarray(limbs[n_b16 : n_b16 + 9]),
+        np.asarray(state["b16"]).reshape(-1).view(np.uint16),
+    )
     back = limbs_to_state(limbs, meta)
     for k in state:
         np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(state[k]))

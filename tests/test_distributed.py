@@ -16,6 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_child(code: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],

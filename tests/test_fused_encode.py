@@ -285,6 +285,7 @@ def test_pipeline_registered_and_declines_non_prologue_irs():
 def run_child(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],

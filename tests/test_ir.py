@@ -558,6 +558,7 @@ def test_remapped_butterfly_on_torus_mesh():
     to collective-permutes only with the committed H·p budget."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = """
         import numpy as np, jax, jax.numpy as jnp
@@ -609,6 +610,7 @@ def test_remapped_butterfly_on_torus3d_mesh():
     (the CI 3D-torus-mesh step)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = """
         import numpy as np, jax, jax.numpy as jnp

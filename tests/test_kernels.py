@@ -1,5 +1,9 @@
 """Pallas kernel validation: interpret=True vs pure-jnp/host oracles, with
-shape and prime sweeps + hypothesis property tests."""
+shape and prime sweeps + hypothesis property tests.
+
+The wrappers default to the compiled TPU kernel (``interpret=False``), so
+every call here asks for the interpreter explicitly; the compiled kernels
+are covered by tests/test_tpu_compile.py and chip_smoke.py."""
 
 import numpy as np
 import pytest
@@ -34,7 +38,7 @@ def rand_u32(shape, q, seed):
 def test_gf_matmul_vs_host_oracle(q, M, K, N):
     a = rand_u32((M, K), q, seed=M + K)
     b = rand_u32((K, N), q, seed=N + K)
-    out = np.asarray(gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q), dtype=np.uint64)
+    out = np.asarray(gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q, interpret=True), dtype=np.uint64)
     want = gf_matmul_host(a, b, q)
     np.testing.assert_array_equal(out, want)
 
@@ -43,7 +47,7 @@ def test_gf_matmul_vs_host_oracle(q, M, K, N):
 def test_gf_matmul_vs_jnp_ref(q):
     a = rand_u32((16, 24), q, seed=0)
     b = rand_u32((24, 8), q, seed=1)
-    out = gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q)
+    out = gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q, interpret=True)
     ref = gf_matmul_ref(jnp.asarray(a), jnp.asarray(b), q)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
@@ -53,7 +57,7 @@ def test_gf_matmul_extreme_values():
     for q in (M31, NTT):
         a = np.full((64, 512), q - 1, dtype=np.uint32)
         b = np.full((512, 128), q - 1, dtype=np.uint32)
-        out = np.asarray(gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q), dtype=np.uint64)
+        out = np.asarray(gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q, interpret=True), dtype=np.uint64)
         want = gf_matmul_host(a, b, q)
         np.testing.assert_array_equal(out, want)
 
@@ -62,7 +66,7 @@ def test_gf_matmul_batched():
     q = M31
     a = rand_u32((6, 9, 17), q, seed=3)
     b = rand_u32((6, 17, 5), q, seed=4)
-    out = np.asarray(gf_matmul_batched(jnp.asarray(a), jnp.asarray(b), q=q), dtype=np.uint64)
+    out = np.asarray(gf_matmul_batched(jnp.asarray(a), jnp.asarray(b), q=q, interpret=True), dtype=np.uint64)
     for i in range(6):
         np.testing.assert_array_equal(out[i], gf_matmul_host(a[i], b[i], q))
 
@@ -79,7 +83,7 @@ def test_gf_matmul_property(m, k, n, qi, seed):
     q = PRIMES[qi]
     a = rand_u32((m, k), q, seed)
     b = rand_u32((k, n), q, seed + 1)
-    out = np.asarray(gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q), dtype=np.uint64)
+    out = np.asarray(gf_matmul(jnp.asarray(a), jnp.asarray(b), q=q, interpret=True), dtype=np.uint64)
     np.testing.assert_array_equal(out, gf_matmul_host(a, b, q))
 
 
@@ -90,7 +94,9 @@ def test_butterfly_mac_vs_ref(q, radix, B, P):
     parts = rng.integers(0, q, size=(radix, B, P), dtype=np.uint32)
     tw = rng.integers(0, q, size=(B, radix), dtype=np.uint32)
     tw_sh = np.asarray(shoup_precompute(tw, q))
-    out = butterfly_mac(jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q)
+    out = butterfly_mac(
+        jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q, interpret=True
+    )
     ref = butterfly_mac_reference(jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # independent host check
@@ -107,7 +113,9 @@ def test_butterfly_mac_payload_dims():
     parts = rng.integers(0, q, size=(2, 16, 3, 5, 7), dtype=np.uint32)
     tw = rng.integers(0, q, size=(16, 2), dtype=np.uint32)
     tw_sh = np.asarray(shoup_precompute(tw, q))
-    out = butterfly_mac(jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q)
+    out = butterfly_mac(
+        jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q, interpret=True
+    )
     assert out.shape == (16, 3, 5, 7)
     ref = butterfly_mac_reference(jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -129,7 +137,8 @@ def test_gf_matmul_block_size_grid(bm, bn, bk, M, K, N):
     b = rand_u32((K, N), q, seed=bn + N)
     out = np.asarray(
         gf_matmul(
-            jnp.asarray(a), jnp.asarray(b), q=q, block_m=bm, block_n=bn, block_k=bk
+            jnp.asarray(a), jnp.asarray(b), q=q, block_m=bm, block_n=bn, block_k=bk,
+            interpret=True,
         ),
         dtype=np.uint64,
     )
@@ -146,7 +155,7 @@ def test_gf_matmul_zero_size_guard(M, K, N):
     q = M31
     a = jnp.zeros((M, K), dtype=jnp.uint32)
     b = jnp.zeros((K, N), dtype=jnp.uint32)
-    out = gf_matmul(a, b, q=q)
+    out = gf_matmul(a, b, q=q, interpret=True)
     assert out.shape == (M, N) and out.dtype == jnp.uint32
     np.testing.assert_array_equal(np.asarray(out), np.zeros((M, N), np.uint32))
 
@@ -157,12 +166,14 @@ def test_gf_matmul_batched_zero_size_guard():
         jnp.zeros((3, 0, 7), dtype=jnp.uint32),
         jnp.zeros((3, 7, 5), dtype=jnp.uint32),
         q=q,
+        interpret=True,
     )
     assert out.shape == (3, 0, 5)
     out = gf_matmul_batched(
         jnp.zeros((2, 4, 0), dtype=jnp.uint32),
         jnp.zeros((2, 0, 5), dtype=jnp.uint32),
         q=q,
+        interpret=True,
     )
     assert out.shape == (2, 4, 5)
     np.testing.assert_array_equal(np.asarray(out), np.zeros((2, 4, 5), np.uint32))
@@ -198,7 +209,10 @@ def test_butterfly_mac_ragged_shapes(B, P):
         parts = rng.integers(0, q, size=(radix, B, P), dtype=np.uint32)
         tw = rng.integers(0, q, size=(B, radix), dtype=np.uint32)
         tw_sh = np.asarray(shoup_precompute(tw, q))
-        out = butterfly_mac(jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q)
+        out = butterfly_mac(
+            jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q,
+            interpret=True,
+        )
         f = Field(q)
         want = np.zeros((B, P), dtype=np.uint64)
         for r in range(radix):
@@ -243,9 +257,24 @@ def test_butterfly_mac_property(b, p_, radix, seed):
     parts = rng.integers(0, q, size=(radix, b, p_), dtype=np.uint32)
     tw = rng.integers(0, q, size=(b, radix), dtype=np.uint32)
     tw_sh = np.asarray(shoup_precompute(tw, q))
-    out = butterfly_mac(jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q)
+    out = butterfly_mac(
+        jnp.asarray(parts), jnp.asarray(tw), jnp.asarray(tw_sh), q=q, interpret=True
+    )
     f = Field(q)
     want = np.zeros((b, p_), dtype=np.uint64)
     for r in range(radix):
         want = f.add(want, f.mul(parts[r], tw[:, r : r + 1]))
     np.testing.assert_array_equal(np.asarray(out, dtype=np.uint64), want)
+
+
+def test_gf_matmul_limbs_fit_signed_int8():
+    """The MXU multiplies int8 as signed, so every limb the kernel feeds it
+    must be a nonnegative int8 (8-bit limbs came back wrong on a v5e for any
+    byte ≥ 128), and the limbs must still rebuild the whole uint32 word."""
+    from repro.kernels.gf_matmul.kernel import _LIMB_BITS, _NLIMB, _limb
+
+    words = np.array([[0, 1, 127, 128, 2**31 - 1, 2**32 - 1]], dtype=np.uint32)
+    limbs = [np.asarray(_limb(jnp.asarray(words), i)) for i in range(_NLIMB)]
+    assert all(l.dtype == np.int8 and l.min() >= 0 for l in limbs)
+    back = sum(l.astype(np.uint64) << np.uint64(_LIMB_BITS * i) for i, l in enumerate(limbs))
+    np.testing.assert_array_equal(back, words.astype(np.uint64))

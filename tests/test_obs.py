@@ -266,6 +266,7 @@ def test_traced_ir_encode_one_span_per_round():
     UNTRACED executor's jaxpr ppermute budget is unchanged."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = """
         import numpy as np, jax, jax.numpy as jnp

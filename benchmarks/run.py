@@ -32,10 +32,9 @@ def main(argv=None) -> None:
 
     tracer = None
     if trace:
-        from repro.obs import Tracer, set_tracer
+        from repro.obs import Tracer
 
         tracer = Tracer()
-        set_tracer(tracer)
 
     print("name,us_per_call,derived")
     failures = []

@@ -160,6 +160,7 @@ def ir_encode_jit(
     topo=None,
     metrics=None,
     kernels: str | None = None,
+    name: str = "ir_encode",
 ):
     """Jitted mesh executor of any :class:`ScheduleIR`: device ``k`` (the
     flattened index over ``axes``, outermost first — exactly how ``P(axes)``
@@ -203,6 +204,11 @@ def ir_encode_jit(
     and ``None`` picks ``"pallas"`` when the mesh's devices are TPUs and
     ``"fused"`` otherwise. All three are bit-exact (differential suite:
     tests/test_fused_encode.py).
+
+    The fused program is named ``jit_<name>`` (``jit_ir_encode`` by
+    default) in HLO and in a device profile, and each CommRound ``r`` and
+    LocalOp ``i`` runs under ``jax.named_scope`` ``round<r>`` / ``local<i>``,
+    so a profile's op metadata says which step an op belongs to.
     """
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
     platform = _mesh_platform(mesh)
@@ -354,16 +360,30 @@ def ir_encode_jit(
     cs_dev = [jnp.asarray(a) for a in consts]
 
     if tracer is None:
+        scopes, n_local = [], 0
+        for op in ops:
+            if op[0] == "comm":
+                scopes.append(f"round{op[2]}")
+            else:
+                scopes.append(f"local{n_local}")
+                n_local += 1
+
         def body(x, cs):
             buf = {INPUT_SLOT: x}
-            for op in ops:
-                buf = apply_op(op, buf, cs)
+            for scope, op in zip(scopes, ops):
+                with jax.named_scope(scope):
+                    buf = apply_op(op, buf, cs)
             return buf[ir.out_slot]
 
         mapped = _smap(
             body, mesh, in_specs=(P(axes), P(axes)), out_specs=P(axes)
         )
-        return jax.jit(lambda x: mapped(x, cs_dev))
+
+        def encode(x):
+            return mapped(x, cs_dev)
+
+        encode.__name__ = encode.__qualname__ = name
+        return jax.jit(encode)
     return _traced_runner(
         mesh, axes, ir, ops, apply_op, cs_dev, tracer, topo, metrics
     )
@@ -602,7 +622,8 @@ def ps_encode_jit(
     Returns ``(fn, plan)``; ``fn`` maps a ``(K, *payload)`` uint32 array
     (sharded or shardable over ``axis``) to the encoded array of the same
     shape. A is a host array: the IR's coefficients and their Shoup duals
-    are baked in as per-device compile-time constants.
+    are baked in as per-device compile-time constants. The program is named
+    ``jit_ps_encode``.
     """
     K = int(mesh.shape[axis])
     A = np.asarray(A)
@@ -611,7 +632,7 @@ def ps_encode_jit(
     plan = plan_prepare_shoot(K, p)
     ir = _apply_pipeline(plan.to_ir(A, q=q), pipeline)
     _check_budget(ir, expected_permute_count(plan))
-    return ir_encode_jit(mesh, axis, ir, q=q, kernels=kernels), plan
+    return ir_encode_jit(mesh, axis, ir, q=q, kernels=kernels, name="ps_encode"), plan
 
 
 def allgather_encode_jit(mesh, axis: str, A: np.ndarray, *, q: int = M31):
@@ -641,7 +662,11 @@ def allgather_encode_jit(mesh, axis: str, A: np.ndarray, *, q: int = M31):
     mapped = _smap(body, mesh, in_specs=(P(axis), P(axis), P(axis)), out_specs=P(axis))
     c_dev = jnp.asarray(cols)
     cs_dev = jnp.asarray(cols_shoup)
-    return jax.jit(lambda x: mapped(x, c_dev, cs_dev))
+
+    def allgather_encode(x):
+        return mapped(x, c_dev, cs_dev)
+
+    return jax.jit(allgather_encode)
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,11 @@
 # Runtime telemetry for the encode pipeline (ROADMAP: live-fed calibration).
 #
-# - trace.py    Tracer/Span: nestable, attributed wall-clock spans; the
-#               instrumented layers (ir_encode_jit(tracer=...), the
-#               interpret oracle, serve.Engine, benchmarks/run.py --trace)
-#               stamp per-CommRound metadata onto them
+# - trace.py    Tracer/Span: nestable, attributed wall-clock spans, each
+#               also a jax.profiler.TraceAnnotation on the profiler's
+#               clock; the instrumented layers (ir_encode_jit(tracer=...),
+#               the interpret oracle, the serving engines and the coded
+#               guard, benchmarks/run.py --trace) stamp their counts and
+#               per-CommRound metadata onto them
 # - export.py   Chrome-trace-event JSON (Perfetto-loadable) + JSONL span
 #               sinks under results/traces/, and the reader for both
 # - metrics.py  process-local counters/gauges/histograms registry with
@@ -39,4 +41,4 @@ from .metrics import (  # noqa: F401
     MetricsRegistry,
     get_registry,
 )
-from .trace import Span, Tracer, current_tracer, set_tracer  # noqa: F401
+from .trace import Span, Tracer, optional_span  # noqa: F401

@@ -1,4 +1,4 @@
-"""Nestable span tracing for the encode pipeline.
+"""Nestable span tracing for the encode pipeline and the serving loop.
 
 A :class:`Tracer` records :class:`Span` records — named, attributed,
 wall-clocked intervals on one monotonic timeline (``time.perf_counter``
@@ -7,22 +7,27 @@ inside another span records the parent index and depth, so an export
 (``repro.obs.export``) can reconstruct the call tree and Perfetto renders
 the nesting from the ``"X"`` complete-event containment.
 
+Every span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+under a running profile it lies on the host lines of the device trace and
+an idle gap on the device can be put down to the span that covered it.
+With no profile running an annotation costs next to nothing. JAX is
+imported on the first span, not with this module.
+
 The tracer is deliberately dumb — no sampling, no threads, no flushing
 policy. Instrumented layers (``dist.collectives.ir_encode_jit(tracer=...)``,
 ``core.simulator.interpret(tracer=...)``, ``serve.engine.Engine``,
+``serve.engine.ContinuousEngine``, ``serve.coded.CodedServeGuard``,
 ``benchmarks/run.py --trace``) open spans around their rounds/steps and
 attach the :class:`~repro.core.ir.CommRound` metadata (round index,
 transfer count, slots on the wire, predicted µs from the α-β model) as
 span attributes; ``repro.obs.feed`` then turns those attributed spans back
-into calibration measurements.
-
-A module-level default tracer (:func:`set_tracer` / :func:`current_tracer`)
-lets entry points like ``benchmarks/run.py --trace`` hand one tracer to
-code they don't call directly.
+into calibration measurements. Code that takes an optional tracer opens
+its spans through :func:`optional_span`.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -71,7 +76,10 @@ class Tracer:
     @contextmanager
     def span(self, name: str, **attrs):
         """Open a nested span; yields the :class:`Span` so callers can add
-        attrs (``sp.attrs["bytes"] = n``) before it closes."""
+        attrs (``sp.attrs["bytes"] = n``) before it closes. The span runs
+        inside a ``TraceAnnotation`` of the same name."""
+        from jax.profiler import TraceAnnotation
+
         sp = Span(
             name=name,
             ts_us=self.now_us(),
@@ -83,7 +91,8 @@ class Tracer:
         self.spans.append(sp)
         self._stack.append(idx)
         try:
-            yield sp
+            with TraceAnnotation(name):
+                yield sp
         finally:
             sp.dur_us = self.now_us() - sp.ts_us
             self._stack.pop()
@@ -92,16 +101,9 @@ class Tracer:
         return [s.to_dict() for s in self.spans]
 
 
-_DEFAULT: Tracer | None = None
-
-
-def set_tracer(tracer: Tracer | None) -> None:
-    """Install ``tracer`` as the process-wide default (None clears it)."""
-    global _DEFAULT
-    _DEFAULT = tracer
-
-
-def current_tracer() -> Tracer | None:
-    """The tracer installed by :func:`set_tracer`, if any — consulted by
-    entry points that cannot take a ``tracer=`` argument directly."""
-    return _DEFAULT
+def optional_span(tracer: Tracer | None, name: str, **attrs):
+    """``tracer.span(name, **attrs)``, or a no-op context (yielding None)
+    when ``tracer`` is None: one code path whether tracing is on or off."""
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(name, **attrs)

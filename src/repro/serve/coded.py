@@ -21,14 +21,20 @@ requests in flight on the dead host are **recovered, not dropped**, and
 the emitted token stream is bit-identical to an unfailed run.
 
 Observability: ``serve.recoveries`` (hosts recovered from), ``serve.
-recovery_us`` (reconstruction latency histogram), ``serve.snapshots``,
-and a ``serve.recovery`` span per event when a tracer is attached.
+recovery_us`` (reconstruction latency histogram), ``serve.snapshots``.
+With a tracer attached, each snapshot is a ``serve.snapshot`` span
+(``tick``) with children ``serve.snapshot.device`` (limbs + encode, up to
+the coded array being ready; ``words``), ``serve.snapshot.to_host``
+(``bytes``) and ``serve.snapshot.store`` (``shards``); each recovery is a
+``serve.recovery`` span (``hosts``, ``tick``) with children
+``serve.recovery.fetch`` (``bytes``, ``responders``),
+``serve.recovery.decode`` (``words``) and ``serve.recovery.to_device``
+(``bytes``).
 """
 
 from __future__ import annotations
 
 import base64
-import contextlib
 import signal
 import subprocess
 import sys
@@ -49,6 +55,7 @@ from repro.coded.lagrange_compute import (
 )
 from repro.coded.rs_checkpoint import shard_state_limbs, unshard_state_limbs
 from repro.core.field import NTT
+from repro.obs.trace import optional_span
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +202,10 @@ class CodedDecodeGroup:
     :class:`ProcessHostPool` child process. The group hands coded shard j
     to host j after each encode, tracks which hosts are alive, and
     rebuilds all K data shards from the first K survivors via Lagrange
-    interpolation (``repro.coded.lcc_decode``)."""
+    interpolation (``repro.coded.lcc_decode``).
+
+    ``tracer`` (set by :meth:`CodedServeGuard.attach`) puts the fetch and
+    the decode of :meth:`reconstruct` under spans of their own."""
 
     def __init__(self, plan, hosts: ProcessHostPool | None = None):
         if hosts is not None and len(hosts) != plan.N:
@@ -206,6 +216,7 @@ class CodedDecodeGroup:
         self.hosts = hosts
         self.alive: set[int] = set(range(plan.N))
         self._mem: dict[int, np.ndarray] = {}
+        self.tracer = None
 
     def store(self, coded: np.ndarray) -> None:
         """Hand coded row j to host j; a host found dead mid-store is
@@ -242,27 +253,33 @@ class CodedDecodeGroup:
         shards. Raises RuntimeError when fewer than K survive — past the
         code's R-failure tolerance there is nothing to interpolate."""
         values, responders = [], []
-        for j in sorted(self.alive):
-            if self.hosts is not None:
-                v = self.hosts.fetch(j)
-                if v is None:  # died between scan and fetch
-                    self.alive.discard(j)
-                    continue
-            else:
-                v = self._mem.get(j)
-                if v is None:
-                    continue
-            values.append(v)
-            responders.append(j)
-            if len(responders) == self.plan.K:
-                break
+        with optional_span(self.tracer, "serve.recovery.fetch") as sp:
+            for j in sorted(self.alive):
+                if self.hosts is not None:
+                    v = self.hosts.fetch(j)
+                    if v is None:  # died between scan and fetch
+                        self.alive.discard(j)
+                        continue
+                else:
+                    v = self._mem.get(j)
+                    if v is None:
+                        continue
+                values.append(v)
+                responders.append(j)
+                if len(responders) == self.plan.K:
+                    break
+            if sp is not None:
+                sp.attrs.update(bytes=sum(v.nbytes for v in values),
+                                responders=len(responders))
         if len(responders) < self.plan.K:
             raise RuntimeError(
                 f"{len(responders)} coded shards survive, need "
                 f"K={self.plan.K} (R={self.plan.R} tolerates at most "
                 f"{self.plan.R} lost hosts)"
             )
-        return lcc_decode(self.plan, np.stack(values), responders)
+        with optional_span(self.tracer, "serve.recovery.decode",
+                           words=sum(v.size for v in values)):
+            return lcc_decode(self.plan, np.stack(values), responders)
 
 
 # ---------------------------------------------------------------------------
@@ -313,9 +330,11 @@ class CodedServeGuard:
             )
         else:
             plan = self.plan
-            self._encode = jax.jit(
-                lambda xp: lcc_encode(plan, xp[: plan.K])
-            )
+
+            def coded_snapshot_encode(xp):
+                return lcc_encode(plan, xp[: plan.K])
+
+            self._encode = jax.jit(coded_snapshot_encode)
         self._meta = None
         self._tick = -1
         self._metrics = None
@@ -330,6 +349,7 @@ class CodedServeGuard:
     # -- engine plumbing ----------------------------------------------------
     def attach(self, metrics, tracer) -> None:
         self._metrics, self._tracer = metrics, tracer
+        self.group.tracer = tracer
 
     @property
     def alive(self) -> set[int]:
@@ -343,12 +363,24 @@ class CodedServeGuard:
     def snapshot(self, cache, state, tick: int) -> None:
         """Encode the decode-path state ((cache, state) pytree → limbs →
         K shards → N coded shards) and hand shard j to host j."""
-        shards, meta = shard_state_limbs((cache, state), self.K)
-        coded = np.asarray(
-            self._encode(lcc_pad(self.plan, shards)), dtype=np.uint32
-        )
-        self._meta, self._tick = meta, tick
-        self.group.store(coded)
+        tracer = self._tracer
+        with optional_span(tracer, "serve.snapshot", tick=tick):
+            with optional_span(tracer, "serve.snapshot.device") as sp:
+                shards, meta = shard_state_limbs((cache, state), self.K)
+                coded = jax.block_until_ready(
+                    self._encode(lcc_pad(self.plan, shards))
+                )
+                if sp is not None:
+                    sp.attrs["words"] = int(shards.size)
+            with optional_span(
+                tracer, "serve.snapshot.to_host", bytes=coded.size * 4
+            ):
+                coded = np.asarray(coded, dtype=np.uint32)
+            self._meta, self._tick = meta, tick
+            with optional_span(tracer, "serve.snapshot.store") as sp:
+                self.group.store(coded)
+                if sp is not None:
+                    sp.attrs["shards"] = len(self.group.alive)
         self.snapshots += 1
         if self._metrics is not None:
             self._metrics.counter("serve.snapshots").inc()
@@ -372,20 +404,19 @@ class CodedServeGuard:
         once fewer than K shards survive — beyond the code's tolerance."""
         if self._meta is None:
             raise RuntimeError("no snapshot taken before recovery")
-        span = (
-            self._tracer.span(
-                "serve.recovery", hosts=str(sorted(dead)), tick=self._tick
-            )
-            if self._tracer is not None
-            else contextlib.nullcontext()
-        )
-        with span:
+        tracer = self._tracer
+        with optional_span(
+            tracer, "serve.recovery", hosts=str(sorted(dead)), tick=self._tick
+        ):
             t0 = time.perf_counter()
             X = self.group.reconstruct()
-            cache, state = unshard_state_limbs(
-                jnp.asarray(X.astype(np.uint32)), self._meta
-            )
-            jax.block_until_ready(jax.tree.leaves(state))
+            with optional_span(
+                tracer, "serve.recovery.to_device", bytes=X.size * 4
+            ):
+                cache, state = unshard_state_limbs(
+                    jnp.asarray(X.astype(np.uint32)), self._meta
+                )
+                jax.block_until_ready(jax.tree.leaves(state))
             dur_us = (time.perf_counter() - t0) * 1e6
         self.recoveries += len(dead)
         self.requests_recovered += requests_in_flight

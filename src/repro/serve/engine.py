@@ -29,9 +29,16 @@ Observability (``repro.obs``): ``serve.steps`` / ``serve.generate_ms`` /
 ``serve.eos_syncs_saved`` on the fixed path; ``serve.prefill_compiles``
 / ``serve.decode_steps`` / ``serve.ttft_ms`` / ``serve.e2e_ms`` /
 ``serve.slot_occupancy`` on the continuous path. Passing ``tracer=``
-wraps prefills and decode chunks in spans and feeds the
-``serve.step_us`` / ``serve.prefill_us`` / ``serve.decode_chunk_us``
-latency histograms (forces a device sync per span — opt-in).
+feeds the ``serve.step_us`` / ``serve.prefill_us`` /
+``serve.decode_chunk_us`` latency histograms and, on the continuous path,
+puts every phase of a serve-loop iteration under a top-level span:
+``serve.admit`` (``admitted``; one ``serve.prefill`` child per request),
+the guard's ``serve.snapshot``, ``serve.decode_chunk`` (``replay=1`` for
+the chunk replayed after a recovery), ``serve.poll``, the guard's
+``serve.recovery``, ``serve.harvest`` (``finished``), and
+``serve.wait`` while no slot is busy and the next arrival is due later.
+The fixed path's step spans force a device sync per step (opt-in); the
+continuous path's spans sync nowhere the untraced loop does not.
 """
 
 from __future__ import annotations
@@ -46,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.model import Model
+from repro.obs.trace import optional_span
 from repro.train.train_loop import make_decode_step, make_prefill_step
 
 from .scheduler import (
@@ -286,6 +294,10 @@ class ContinuousEngine:
 
         return get_registry()
 
+    def _span(self, name: str, **attrs):
+        """A span of the engine's tracer; a no-op context without one."""
+        return optional_span(self._tracer, name, **attrs)
+
     @property
     def prefill_compiles(self) -> int:
         """Compiled prefill graphs over this engine's lifetime — bounded by
@@ -475,18 +487,19 @@ class ContinuousEngine:
         while sched.has_work:
             # 1. refill free slots with every arrived request (mid-decode
             #    insertion: the rest of the batch is untouched)
-            while (a := sched.next_assignment(now())) is not None:
-                slot, req = a
-                plen = len(req.prompt)
-                bucket = bucket_for(plen, self.buckets)
-                pf = self._prefill_for(bucket, greedy)
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :plen] = req.prompt
-                rng_kd = jax.random.key_data(
-                    jax.random.fold_in(base_key, _request_seed(req))
-                )
-                if tracer is not None:
-                    with tracer.span(
+            with self._span("serve.admit") as admit_sp:
+                admitted = 0
+                while (a := sched.next_assignment(now())) is not None:
+                    slot, req = a
+                    plen = len(req.prompt)
+                    bucket = bucket_for(plen, self.buckets)
+                    pf = self._prefill_for(bucket, greedy)
+                    toks = np.zeros((1, bucket), np.int32)
+                    toks[0, :plen] = req.prompt
+                    rng_kd = jax.random.key_data(
+                        jax.random.fold_in(base_key, _request_seed(req))
+                    )
+                    with self._span(
                         "serve.prefill", slot=slot, bucket=bucket, plen=plen
                     ) as sp:
                         cache, state = pf(
@@ -494,19 +507,16 @@ class ContinuousEngine:
                             jnp.int32(slot), jnp.int32(plen),
                             jnp.int32(req.max_new_tokens), eos, rng_kd, temp,
                         )
+                        # first token is materialized here — that's TTFT
                         jax.block_until_ready(state["last_tok"])
-                    reg.histogram("serve.prefill_us").observe(sp.dur_us)
-                else:
-                    cache, state = pf(
-                        self.params, cache, state, jnp.asarray(toks),
-                        jnp.int32(slot), jnp.int32(plen),
-                        jnp.int32(req.max_new_tokens), eos, rng_kd, temp,
-                    )
-                    # first token is materialized here — that's TTFT
-                    jax.block_until_ready(state["last_tok"])
-                ttft = now() - req.arrival_s
-                meta[slot] = (req, ttft)
-                reg.histogram("serve.ttft_ms").observe(ttft * 1e3)
+                    if sp is not None:
+                        reg.histogram("serve.prefill_us").observe(sp.dur_us)
+                    ttft = now() - req.arrival_s
+                    meta[slot] = (req, ttft)
+                    reg.histogram("serve.ttft_ms").observe(ttft * 1e3)
+                    admitted += 1
+                if admit_sp is not None:
+                    admit_sp.attrs["admitted"] = admitted
             occ = sched.occupied
             if not occ:
                 nxt_arr = sched.next_arrival_s()
@@ -514,7 +524,8 @@ class ContinuousEngine:
                     break  # queue drained, all slots retired
                 wait = nxt_arr - now()
                 if wait > 0:
-                    time.sleep(wait)
+                    with self._span("serve.wait"):
+                        time.sleep(wait)
                 continue
             # 2. one decode chunk: sync_every fully-async ticks, then a
             #    single host sync on the active mask to detect retirements.
@@ -522,19 +533,18 @@ class ContinuousEngine:
             #    so a host lost mid-chunk costs one reconstruct + replay.
             if guard is not None:
                 guard.snapshot(cache, state, tick=decode_steps)
-            if tracer is not None:
-                with tracer.span(
-                    "serve.decode_chunk", ticks=sync_every, occupied=len(occ)
-                ) as sp:
-                    cache, state, active_now = run_chunk(cache, state)
-                reg.histogram("serve.decode_chunk_us").observe(sp.dur_us)
-            else:
+            with self._span(
+                "serve.decode_chunk", ticks=sync_every, occupied=len(occ)
+            ) as sp:
                 cache, state, active_now = run_chunk(cache, state)
+            if sp is not None:
+                reg.histogram("serve.decode_chunk_us").observe(sp.dur_us)
             decode_steps += sync_every
             ticks_active += len(occ) * sync_every
             ticks_total += S * sync_every
             if guard is not None:
-                dead = guard.poll(decode_steps)
+                with self._span("serve.poll"):
+                    dead = guard.poll(decode_steps)
                 if dead:
                     # exact chunk-start state from any K survivors, then a
                     # deterministic replay (the PRNG lives in the state) —
@@ -542,26 +552,31 @@ class ContinuousEngine:
                     cache, state = guard.recover(
                         dead, requests_in_flight=len(occ)
                     )
-                    cache, state, active_now = run_chunk(cache, state)
+                    with self._span(
+                        "serve.decode_chunk", ticks=sync_every,
+                        occupied=len(occ), replay=1,
+                    ):
+                        cache, state, active_now = run_chunk(cache, state)
             # 3. harvest + retire finished slots (they refill next iteration)
             finished = [s for s in occ if not active_now[s]]
-            if finished:
-                gen_counts = np.asarray(state["gen_count"])
-                gen_buf = np.asarray(state["gen_buf"])
-                for s in finished:
-                    req, ttft = meta.pop(s)
-                    sched.retire(s)
-                    g = int(gen_counts[s])
-                    e2e = now() - req.arrival_s
-                    results[req.id] = RequestResult(
-                        id=req.id,
-                        tokens=list(req.prompt) + gen_buf[s, :g].tolist(),
-                        prompt_len=len(req.prompt),
-                        gen_len=g,
-                        ttft_s=ttft,
-                        e2e_s=e2e,
-                    )
-                    reg.histogram("serve.e2e_ms").observe(e2e * 1e3)
+            with self._span("serve.harvest", finished=len(finished)):
+                if finished:
+                    gen_counts = np.asarray(state["gen_count"])
+                    gen_buf = np.asarray(state["gen_buf"])
+                    for s in finished:
+                        req, ttft = meta.pop(s)
+                        sched.retire(s)
+                        g = int(gen_counts[s])
+                        e2e = now() - req.arrival_s
+                        results[req.id] = RequestResult(
+                            id=req.id,
+                            tokens=list(req.prompt) + gen_buf[s, :g].tolist(),
+                            prompt_len=len(req.prompt),
+                            gen_len=g,
+                            ttft_s=ttft,
+                            e2e_s=e2e,
+                        )
+                        reg.histogram("serve.e2e_ms").observe(e2e * 1e3)
         wall_s = now()
         out = [results[r.id] for r in ordered]
         gen_total = sum(r.gen_len for r in out)

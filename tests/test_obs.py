@@ -21,13 +21,11 @@ from repro.obs import (
     MetricsRegistry,
     Span,
     Tracer,
-    current_tracer,
     drift_rows,
     feed_calibration,
     read_spans,
     refit_from_spans,
     round_measurements,
-    set_tracer,
     spans_to_chrome,
     write_chrome_trace,
     write_spans_jsonl,
@@ -102,16 +100,6 @@ def test_chrome_trace_roundtrip(tmp_path):
         assert check_trace.main([str(jsonl)]) == 0
     finally:
         sys.path.pop(0)
-
-
-def test_default_tracer_install():
-    tr = Tracer()
-    set_tracer(tr)
-    try:
-        assert current_tracer() is tr
-    finally:
-        set_tracer(None)
-    assert current_tracer() is None
 
 
 # ---------------------------------------------------------------------------

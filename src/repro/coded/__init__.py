@@ -8,6 +8,7 @@ from .lagrange_compute import (  # noqa: F401
     build_lcc,
     lcc_compute_and_decode,
     lcc_decode,
+    lcc_decode_device,
     lcc_encode,
     lcc_encode_collective,
     lcc_generator,

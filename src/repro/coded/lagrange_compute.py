@@ -24,7 +24,12 @@ Two regimes:
   reconstruct every X_i bit-exactly (:func:`lcc_decode`).
 
 Everything is exact over GF(q) (data quantized to field elements), so the
-decode is bit-exact.
+decode is bit-exact. The decode runs in two forms: :func:`lcc_decode`, host
+numpy, the exact oracle; and :func:`lcc_decode_device`, one compiled device
+program whose K×K Lagrange matrix (and its Shoup duals) are runtime
+arguments, so every K-subset of responders shares one executable — the
+universal point of the paper: the same schedule, only the coefficients
+change. Coded serving rebuilds its state with the device form.
 """
 
 from __future__ import annotations
@@ -33,10 +38,11 @@ from dataclasses import dataclass
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.draw_loose import encode_lagrange
-from repro.core.field import M31, NTT, Field
+from repro.core.field import M31, NTT, Field, madd, shoup_mul, shoup_precompute
 from repro.core.matrices import distinct_points, lagrange_matrix
 from repro.core.prepare_shoot import encode_universal
 from repro.core.schedule import plan_draw_loose, plan_prepare_shoot
@@ -192,6 +198,61 @@ def lcc_decode(plan: LCCPlan, values: np.ndarray, responders) -> np.ndarray:
     return out.reshape((K,) + Y.shape[1:])
 
 
+def lcc_interpolate(rows, L, L_pre, *, q: int):
+    """out[k] = Σ_i L[i, k] · rows[i] mod q for K (1, S) uint32 rows, uint32
+    only (Shoup products, modular adds); returns the (K, S) stack."""
+    K = len(rows)
+    out = []
+    for k in range(K):
+        acc = shoup_mul(rows[0], L[0, k], L_pre[0, k], q)
+        for i in range(1, K):
+            acc = madd(acc, shoup_mul(rows[i], L[i, k], L_pre[i, k], q), q)
+        out.append(acc)
+    return jnp.concatenate(out, axis=0)
+
+
+#: the coefficients are arguments, so one executable per (K, S) and q serves
+#: every responder set
+_interpolate = jax.jit(lcc_interpolate, static_argnames="q")
+
+
+def compile_lcc_decode_device(plan: LCCPlan, S: int):
+    """Compile :func:`lcc_decode_device` for shards of S words ahead of the
+    first decode; returns the compiled program (``memory_analysis()``)."""
+    K, u32 = plan.K, jnp.uint32
+    return _interpolate.lower(
+        [jax.ShapeDtypeStruct((1, S), u32)] * K,
+        jax.ShapeDtypeStruct((K, K), u32),
+        jax.ShapeDtypeStruct((K, K), u32),
+        q=plan.q,
+    ).compile()
+
+
+def lcc_decode_device(plan: LCCPlan, shards, responders) -> jax.Array:
+    """:func:`lcc_decode` on the device: ``shards[i]``, the host uint32
+    array of S words held by ``responders[i]``, is uploaded as it is (one
+    row each: stacking them on the host first costs a copy of the whole
+    payload) and one compiled program interpolates. Returns the K data
+    blocks as a (K, S) uint32 device array, bit-identical to
+    :func:`lcc_decode`. Needs exactly K distinct responders; the (K, K)
+    Lagrange matrix for them is built on the host."""
+    responders = [int(r) for r in responders]
+    _validate_responders(plan, responders)
+    if len(responders) != plan.K or len(shards) != plan.K:
+        raise ValueError(
+            f"the device decode takes exactly K={plan.K} shards and "
+            f"responders, got {len(shards)} and {len(responders)}"
+        )
+    # data[k] = Σ_i L[i, k] · shards[i]: L takes the values at the
+    # responders' α (in the order given) to the K data points ω
+    L = lagrange_matrix(
+        Field(plan.q), np.asarray(plan.omega_points),
+        np.asarray(plan.alpha_points)[responders],
+    )
+    rows = [jax.device_put(np.asarray(v, np.uint32).reshape(1, -1)) for v in shards]
+    return _interpolate(rows, L.astype(np.uint32), shoup_precompute(L, plan.q), q=plan.q)
+
+
 def lcc_compute_and_decode(
     plan: LCCPlan, encoded: np.ndarray, W: np.ndarray, responders: list[int]
 ) -> np.ndarray:
@@ -214,5 +275,7 @@ __all__ = [
     "lcc_encode",
     "lcc_encode_collective",
     "lcc_decode",
+    "lcc_decode_device",
+    "compile_lcc_decode_device",
     "lcc_compute_and_decode",
 ]

@@ -15,8 +15,11 @@ A :class:`FaultInjector` kills hosts at scheduled decode ticks (or a
 :class:`ProcessHostPool` host — a real OS process holding its shard —
 is SIGKILLed). The engine detects the fault at the next chunk sync,
 :class:`CodedServeGuard` reconstructs the exact chunk-start state from any
-K of the surviving shards via Lagrange interpolation
-(``repro.coded.lcc_decode``), and the chunk replays deterministically —
+K of the surviving shards via Lagrange interpolation on the device
+(``repro.coded.lcc_decode_device``: the K surviving uint32 shards are
+uploaded and interpolated by one compiled program, compiled at the first
+snapshot of a shape so that no recovery compiles), and the chunk replays
+deterministically —
 requests in flight on the dead host are **recovered, not dropped**, and
 the emitted token stream is bit-identical to an unfailed run.
 
@@ -27,9 +30,10 @@ With a tracer attached, each snapshot is a ``serve.snapshot`` span
 the coded array being ready; ``words``), ``serve.snapshot.to_host``
 (``bytes``) and ``serve.snapshot.store`` (``shards``); each recovery is a
 ``serve.recovery`` span (``hosts``, ``tick``) with children
-``serve.recovery.fetch`` (``bytes``, ``responders``),
-``serve.recovery.decode`` (``words``) and ``serve.recovery.to_device``
-(``bytes``).
+``serve.recovery.fetch`` (``bytes``, ``responders``; the host shards),
+``serve.recovery.decode`` (``words``; upload of the K survivors and the
+device interpolation, up to ready) and ``serve.recovery.to_device``
+(``bytes``; the unshard into the engine's state, up to ready).
 """
 
 from __future__ import annotations
@@ -44,11 +48,11 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.coded.lagrange_compute import (
     build_lcc,
-    lcc_decode,
+    compile_lcc_decode_device,
+    lcc_decode_device,
     lcc_encode,
     lcc_encode_collective,
     lcc_pad,
@@ -200,9 +204,10 @@ class CodedDecodeGroup:
 
     A "host" is either an in-memory slot (default) or one
     :class:`ProcessHostPool` child process. The group hands coded shard j
-    to host j after each encode, tracks which hosts are alive, and
-    rebuilds all K data shards from the first K survivors via Lagrange
-    interpolation (``repro.coded.lcc_decode``).
+    to host j after each encode (host uint32 arrays), tracks which hosts
+    are alive, and rebuilds all K data shards from the first K survivors
+    via Lagrange interpolation on the device
+    (``repro.coded.lcc_decode_device``).
 
     ``tracer`` (set by :meth:`CodedServeGuard.attach`) puts the fetch and
     the decode of :meth:`reconstruct` under spans of their own."""
@@ -248,10 +253,11 @@ class CodedDecodeGroup:
         self.alive.difference_update(dead)
         return dead
 
-    def reconstruct(self) -> np.ndarray:
+    def reconstruct(self) -> jax.Array:
         """All K data shards, bit-exact, from the first K surviving coded
-        shards. Raises RuntimeError when fewer than K survive — past the
-        code's R-failure tolerance there is nothing to interpolate."""
+        shards, as a (K, S) uint32 device array, ready. Raises RuntimeError
+        when fewer than K survive — past the code's R-failure tolerance
+        there is nothing to interpolate."""
         values, responders = [], []
         with optional_span(self.tracer, "serve.recovery.fetch") as sp:
             for j in sorted(self.alive):
@@ -279,7 +285,9 @@ class CodedDecodeGroup:
             )
         with optional_span(self.tracer, "serve.recovery.decode",
                            words=sum(v.size for v in values)):
-            return lcc_decode(self.plan, np.stack(values), responders)
+            return jax.block_until_ready(
+                lcc_decode_device(self.plan, values, responders)
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -336,6 +344,7 @@ class CodedServeGuard:
 
             self._encode = jax.jit(coded_snapshot_encode)
         self._meta = None
+        self._decode_shapes: set[tuple[int, ...]] = set()
         self._tick = -1
         self._metrics = None
         self._tracer = None
@@ -362,7 +371,9 @@ class CodedServeGuard:
 
     def snapshot(self, cache, state, tick: int) -> None:
         """Encode the decode-path state ((cache, state) pytree → limbs →
-        K shards → N coded shards) and hand shard j to host j."""
+        K shards → N coded shards) and hand shard j to host j. The first
+        snapshot of a shard shape also compiles the recovery's decode for
+        it, so that a recovery compiles nothing."""
         tracer = self._tracer
         with optional_span(tracer, "serve.snapshot", tick=tick):
             with optional_span(tracer, "serve.snapshot.device") as sp:
@@ -381,6 +392,9 @@ class CodedServeGuard:
                 self.group.store(coded)
                 if sp is not None:
                     sp.attrs["shards"] = len(self.group.alive)
+        if shards.shape not in self._decode_shapes:
+            compile_lcc_decode_device(self.plan, shards.shape[1])
+            self._decode_shapes.add(shards.shape)
         self.snapshots += 1
         if self._metrics is not None:
             self._metrics.counter("serve.snapshots").inc()
@@ -400,7 +414,8 @@ class CodedServeGuard:
 
     def recover(self, dead: list[int], requests_in_flight: int = 0):
         """Rebuild the chunk-start (cache, state) bit-exactly from any K
-        surviving coded shards (Lagrange interpolation). Raises RuntimeError
+        surviving coded shards (Lagrange interpolation on the device, then
+        the unshard into the engine's state). Raises RuntimeError
         once fewer than K shards survive — beyond the code's tolerance."""
         if self._meta is None:
             raise RuntimeError("no snapshot taken before recovery")
@@ -413,10 +428,8 @@ class CodedServeGuard:
             with optional_span(
                 tracer, "serve.recovery.to_device", bytes=X.size * 4
             ):
-                cache, state = unshard_state_limbs(
-                    jnp.asarray(X.astype(np.uint32)), self._meta
-                )
-                jax.block_until_ready(jax.tree.leaves(state))
+                cache, state = unshard_state_limbs(X, self._meta)
+                jax.block_until_ready((cache, state))
             dur_us = (time.perf_counter() - t0) * 1e6
         self.recoveries += len(dead)
         self.requests_recovered += requests_in_flight

@@ -25,6 +25,7 @@ from repro.coded import (
     unshard_state_limbs,
     worker_combine,
 )
+from repro.coded.lagrange_compute import _interpolate, lcc_decode_device
 from repro.core.field import M31, NTT, Field
 from repro.core.matrices import cauchy_matrix
 
@@ -239,3 +240,52 @@ def test_lcc_erasure_roundtrip_property(K, R, pay, seed):
     np.testing.assert_array_equal(
         lcc_decode(plan, coded[survivors], survivors), X % q
     )
+
+
+# ---------------------------------------------------------------------------
+# the device decode: one compiled program, coefficients as arguments
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("q", [NTT, M31])
+@pytest.mark.parametrize("K,R", [(3, 1), (4, 2)])
+def test_lcc_decode_device_matches_host_for_every_subset(K, R, q):
+    """The device decode equals the host oracle for every K-subset of the N
+    responders, in any order, on data that holds 0 and q − 1; the encoded
+    data comes back; one executable serves every subset."""
+    import itertools
+
+    plan = build_lcc(K, p=1, q=q, R=R)
+    S = 1000 + 10 * K + R + (q & 7)  # a shape no other test compiles
+    rng = np.random.default_rng(K * 31 + R)
+    X = rng.integers(0, q, size=(K, S), dtype=np.uint64)
+    X[:, :4] = [0, q - 1, 0, q - 1]
+    coded = np.asarray(lcc_encode(plan, jnp.asarray(X)), dtype=np.uint32)
+    Y = rng.integers(0, q, size=(plan.N, S), dtype=np.uint64).astype(np.uint32)
+    Y[:, 4:8] = [q - 1, 0, q - 1, 0]
+    before = _interpolate._cache_size()
+    for subset in itertools.combinations(range(plan.N), K):
+        resp = list(subset)[::-1] if sum(subset) % 2 else list(subset)
+        got = lcc_decode_device(plan, coded[resp], resp)
+        assert isinstance(got, jax.Array)
+        assert got.dtype == jnp.uint32 and got.shape == (K, S)
+        np.testing.assert_array_equal(np.asarray(got), X)
+        np.testing.assert_array_equal(
+            np.asarray(lcc_decode_device(plan, list(Y[resp]), resp)),
+            lcc_decode(plan, Y[resp], resp),
+        )
+    assert _interpolate._cache_size() == before + 1
+
+
+def test_lcc_decode_device_takes_exactly_k_distinct_responders():
+    K, R = 3, 2
+    plan = build_lcc(K, R=R)
+    Y = np.zeros((K + 1, 5), np.uint32)
+    with pytest.raises(ValueError, match="need ≥3 responders"):
+        lcc_decode_device(plan, Y[: K - 1], list(range(K - 1)))
+    with pytest.raises(ValueError, match="exactly K=3"):
+        lcc_decode_device(plan, Y, list(range(K + 1)))
+    with pytest.raises(ValueError, match="duplicate"):
+        lcc_decode_device(plan, Y[:K], [0, 0, 1])
+    with pytest.raises(ValueError, match="outside"):
+        lcc_decode_device(plan, Y[:K], [0, 1, K + R])

@@ -16,6 +16,7 @@ Acceptance:
   ordered recovery percentiles, token-identity flag).
 """
 
+import contextlib
 import functools
 import itertools
 import json
@@ -31,6 +32,7 @@ from hyputil import given, settings, st
 import jax
 
 from repro.configs import smoke_config
+from repro.launch.compile_cache import CompileLog
 from repro.models import build_model
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
@@ -213,6 +215,78 @@ def test_coded_serve_sampled_survivor_subsets_above_8(seed):
     rep = eng.serve(_reqs(), greedy=True, sync_every=2, guard=guard)
     assert _toks(rep) == _baseline(), f"diverged for killed={killed}"
     assert rep.recoveries == Rb
+
+
+# ---------------------------------------------------------------------------
+# the device decode under the benchmark's code (K = 3, R = 1)
+# ---------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def _counting_compiles():
+    """A :class:`CompileLog` that counts the backend compiles in the block."""
+    log = CompileLog(cache_dir="")
+    jax.monitoring.register_event_duration_secs_listener(log._on_duration)
+    try:
+        yield log
+    finally:
+        jax.monitoring.unregister_event_duration_listener(log._on_duration)
+
+
+@pytest.mark.parametrize("host", range(4))
+def test_coded_serve_device_recovery_per_killed_host(host):
+    """K = 3, R = 1: whichever host dies, the rebuilt state comes back from
+    the device decode as a (K, S) uint32 device array, compiled at the
+    first snapshot (the rebuild compiles nothing), and the tokens equal
+    the unfailed run's."""
+    eng = _engine()
+    guard = CodedServeGuard(K=3, R=1, injector=FaultInjector(kills=((1, host),)))
+    reconstruct = guard.group.reconstruct
+    seen = {}
+
+    def watched():
+        with _counting_compiles() as log:
+            seen["X"] = reconstruct()
+        seen["compiles"] = log.compiles
+        return seen["X"]
+
+    guard.group.reconstruct = watched
+    rep = eng.serve(_reqs(), greedy=True, sync_every=2, guard=guard)
+    assert _toks(rep) == _baseline()
+    assert rep.recoveries == 1 and sorted(guard.alive) == [
+        h for h in range(4) if h != host]
+    X = seen["X"]
+    S = -(-guard._meta.total // 3)
+    assert isinstance(X, jax.Array)
+    assert X.dtype == np.uint32 and X.shape == (3, S)
+    assert seen["compiles"] == 0
+    # the hosts still hold host uint32 shards
+    assert all(isinstance(v, np.ndarray) and v.dtype == np.uint32
+               and v.shape == (S,) for v in guard.group._mem.values())
+
+
+def test_recovery_after_a_snapshot_compiles_nothing():
+    """Once a snapshot has been taken and the unshard of an uploaded (K, S)
+    array has run (as a serving warm-up leaves it), a whole recovery —
+    upload, device decode, unshard — compiles nothing, and gives the
+    snapshot's state back."""
+    import jax.numpy as jnp
+
+    from repro.coded import shard_state_limbs, unshard_state_limbs
+
+    state = {"x": jnp.linspace(-3.0, 3.0, 1001, dtype=jnp.float32),
+             "n": jnp.arange(77, dtype=jnp.int32)}
+    guard = CodedServeGuard(K=3, R=1, injector=FaultInjector(kills=((0, 1),)))
+    guard.snapshot({}, state, tick=0)
+    shards, meta = shard_state_limbs(({}, state), 3)
+    unshard_state_limbs(jnp.asarray(np.zeros(shards.shape, np.uint32)), meta)
+    dead = guard.poll(4)
+    assert dead == [1]
+    with _counting_compiles() as log:
+        _, back = guard.recover(dead)
+    assert log.compiles == 0
+    for k in state:
+        np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(state[k]))
 
 
 # ---------------------------------------------------------------------------

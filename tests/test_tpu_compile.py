@@ -111,6 +111,26 @@ def test_ps_encode_compiles_permute_only(topo):
     assert "all-gather" not in text
 
 
+def test_lcc_decode_device_fits(one_chip):
+    """The recovery's device decode at the coded pool's shard size (K = 3
+    shards of 78,294,374 words, qwen3-1.7b at 4 × 1024), compiled from the
+    uploaded rows: its temporaries stay under 1 GB."""
+    from repro.coded.lagrange_compute import _interpolate, build_lcc
+
+    plan = build_lcc(3, R=1)
+    S = 78_294_374
+    compiled = _interpolate.lower(
+        [_u32((1, S), one_chip)] * 3, _u32((3, 3), one_chip), _u32((3, 3), one_chip),
+        q=plan.q,
+    ).compile()
+    mem = compiled.memory_analysis()
+    print(f"decode 3x{S}: arguments {mem.argument_size_in_bytes} B, temporaries "
+          f"{mem.temp_size_in_bytes} B, outputs {mem.output_size_in_bytes} B")
+    assert mem.argument_size_in_bytes >= 3 * S * 4
+    assert mem.output_size_in_bytes >= 3 * S * 4
+    assert mem.temp_size_in_bytes < 1_000_000_000
+
+
 def _engine_program(one_chip, which: str, slots: int, max_len: int):
     """The serving engine's own jitted decode tick or 256-token prefill for
     qwen3-1.7b at its published widths, compiled from the shapes of the

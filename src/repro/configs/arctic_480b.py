@@ -19,7 +19,6 @@ CONFIG = ModelConfig(
         top_k=2,
         expert_ff=4864,
         dense_residual_ff=4864,
-        router_softmax_topk=True,
     ),
     source="hf:Snowflake/snowflake-arctic-base (35L d7168 56H kv8 ff4864 v32000, 128e top-2 + dense residual)",
 )

@@ -8,7 +8,7 @@ from dataclasses import dataclass, field
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int  # the router's width: every routed expert of the layer
     top_k: int
     expert_ff: int
     shared_ff: int = 0  # width of always-on shared expert(s); 0 = none
@@ -17,9 +17,23 @@ class MoEConfig:
     layer_offset: int = 0  # ... starting at `offset`
     first_dense: int = 0  # leading dense layers (DeepSeek-V3: 3)
     dense_ff: int = 0  # d_ff of the dense layers when first_dense > 0
-    capacity_factor: float = 1.25
-    router_softmax_topk: bool = True  # False → topk-then-softmax (DeepSeek)
+    capacity_factor: float = 1.25  # the training forward's capacity path only
+    # "softmax" (softmax over all experts, then top-k) | "sigmoid" (DeepSeek-V3
+    # noaux_tc: top-k of sigmoid + a selection bias within the best groups)
+    scoring: str = "softmax"
     norm_topk_prob: bool = False
+    n_group: int = 1  # expert groups (sigmoid scoring) ...
+    topk_group: int = 1  # ... of which each token may use this many
+    routed_scaling_factor: float = 1.0
+    # the experts whose weights this chip holds: ids [held_first,
+    # held_first + n_held) of the n_experts the router scores (expert
+    # parallelism's share); n_held 0 → all of them
+    held_first: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -29,6 +43,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling as DeepSeek-V3 publishes it (``rope_scaling``
+    with ``mscale = mscale_all_dim = 1``: cos/sin stay unscaled and the
+    attention's softmax scale is multiplied by ``yarn_mscale(factor) ** 2``)."""
+
+    factor: float = 40.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max_position: int = 4096
 
 
 @dataclass(frozen=True)
@@ -68,6 +94,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 1e4
+    yarn: YarnConfig | None = None  # YaRN-scaled rotary frequencies (MLA only)
     tie_embeddings: bool = False
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None

@@ -38,6 +38,8 @@ ARCHS: dict[str, ModelConfig] = {
         whisper_base,
     )
 }
+# one chip's share of an expert-parallel DeepSeek-V3 deployment
+ARCHS[deepseek_v3_671b.EP32.name] = deepseek_v3_671b.EP32
 
 
 def get(name: str) -> ModelConfig:
@@ -62,18 +64,26 @@ def smoke_config(name: str) -> ModelConfig:
         remat="none",
     )
     if cfg.moe:
+        mc = cfg.moe
+        grouped = mc.n_group > 1
+        n_exp = 8 if grouped else 4
         kw["moe"] = MoEConfig(
-            n_experts=4,
-            top_k=min(cfg.moe.top_k, 2),
+            n_experts=n_exp,
+            top_k=min(mc.top_k, 2),
             expert_ff=32,
-            shared_ff=32 if cfg.moe.shared_ff else 0,
-            dense_residual_ff=32 if cfg.moe.dense_residual_ff else 0,
-            layer_period=cfg.moe.layer_period,
-            layer_offset=cfg.moe.layer_offset,
-            first_dense=min(cfg.moe.first_dense, 1),
-            dense_ff=96 if cfg.moe.dense_ff else 0,
-            router_softmax_topk=cfg.moe.router_softmax_topk,
-            norm_topk_prob=cfg.moe.norm_topk_prob,
+            shared_ff=32 if mc.shared_ff else 0,
+            dense_residual_ff=32 if mc.dense_residual_ff else 0,
+            layer_period=mc.layer_period,
+            layer_offset=mc.layer_offset,
+            first_dense=min(mc.first_dense, 1),
+            dense_ff=96 if mc.dense_ff else 0,
+            scoring=mc.scoring,
+            norm_topk_prob=mc.norm_topk_prob,
+            n_group=2 if grouped else 1,
+            topk_group=1,
+            routed_scaling_factor=mc.routed_scaling_factor,
+            # a held share keeps its shape: a quarter of the experts, the first
+            n_held=n_exp // 4 if mc.n_held else 0,
         )
     if cfg.mla:
         kw["mla"] = MLAConfig(

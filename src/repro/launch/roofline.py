@@ -82,9 +82,9 @@ def param_counts(cfg) -> dict:
         total += mix
         active += mix
         if cfg.moe and i >= prefix_dense and (i % cfg.moe.layer_period) == cfg.moe.layer_offset % cfg.moe.layer_period:
-            e = cfg.moe
-            total += e.n_experts * 3 * d * e.expert_ff + d * e.n_experts
-            active += e.top_k * 3 * d * e.expert_ff + d * e.n_experts
+            e = cfg.moe  # the experts held here; a token reaches at most top_k of them
+            total += e.held * 3 * d * e.expert_ff + d * e.n_experts
+            active += min(e.top_k, e.held) * 3 * d * e.expert_ff + d * e.n_experts
             if e.shared_ff:
                 total += 3 * d * e.shared_ff
                 active += 3 * d * e.shared_ff

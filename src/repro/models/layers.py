@@ -75,10 +75,35 @@ def layernorm(params, x, eps=1e-5):
     return y.astype(x.dtype)
 
 
-def rope_angles(positions, head_dim, theta):
-    """positions: (...,) int32 → (cos, sin): (..., head_dim/2) f32."""
+def yarn_mscale(factor: float) -> float:
+    """YaRN's attention temperature term, ``0.1 ln(factor) + 1``."""
+    return 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_freqs(head_dim, theta, yarn=None) -> np.ndarray:
+    """The head_dim/2 rotary frequencies; with ``yarn`` (a ``YarnConfig``)
+    YaRN's blend as DeepSeek-V3 publishes it: frequencies that turn more
+    than ``beta_fast`` times over the original context are kept, those
+    that turn fewer than ``beta_slow`` times are divided by ``factor``, and
+    a linear ramp over the dimensions blends the two between."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) * 2.0 / head_dim))
+    if yarn is None:
+        return freqs
+
+    def dim_of(rotations):
+        return (head_dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / max(high - low, 1e-3), 0, 1)
+    return (freqs / yarn.factor * ramp + freqs * (1 - ramp)).astype(np.float32)
+
+
+def rope_angles(positions, head_dim, theta, yarn=None):
+    """positions: (...,) int32 → (cos, sin): (..., head_dim/2) f32."""
+    freqs = rope_freqs(head_dim, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freqs[None, :]
     return jnp.cos(ang), jnp.sin(ang)
 
@@ -114,16 +139,17 @@ def _attn_chunk(q, k, v, scale, mask):
 
 
 def chunked_causal_attention(q, k, v, *, chunk_q=1024, chunk_k=1024, causal=True,
-                             q_offset=0):
+                             q_offset=0, scale=None):
     """q: (B,Hq,Sq,D), k/v: (B,Hkv,Sk,D) → (B,Hq,Sq,D) in q.dtype.
 
     Online-softmax over KV chunks inside a scan over Q chunks. ``q_offset``
-    is the absolute position of q[0] (for prefill continuation / decode).
+    is the absolute position of q[0] (for prefill continuation / decode);
+    ``scale`` multiplies the scores (default 1/sqrt(D)).
     """
     B, Hq, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     cq = min(chunk_q, Sq)
     ck = min(chunk_k, Sk)
     # pad to multiples
@@ -353,8 +379,16 @@ def gelu_mlp(params, x, ctx=NO_CTX):
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k, capacity-based sort dispatch — FLOPs ∝ active experts)
+# MoE: routing, then the held experts' part of the result
 # ---------------------------------------------------------------------------
+# Two dispatches share one router:
+# * ``moe_dropless`` (serving: decode and prefill): the (token, expert)
+#   pairs whose expert is held here, sorted by expert, through grouped
+#   products (``jax.lax.ragged_dot``) — no capacity, no dropped token,
+#   FLOPs in the held pairs. It is the only path for a held share.
+# * ``moe_block``'s capacity path (the training forward and the dry-run):
+#   per-expert buffers of capacity C, which expert-sharded GSPMD
+#   partitions; tokens past C are dropped.
 
 
 def moe_init(key, cfg, dtype=jnp.bfloat16):
@@ -363,10 +397,13 @@ def moe_init(key, cfg, dtype=jnp.bfloat16):
     ks = jax.random.split(key, 5)
     p = {
         "router": truncnorm_init(ks[0], (d, mc.n_experts), jnp.float32, scale=0.006),
-        "w_gate": truncnorm_init(ks[1], (mc.n_experts, d, mc.expert_ff), dtype),
-        "w_up": truncnorm_init(ks[2], (mc.n_experts, d, mc.expert_ff), dtype),
-        "w_down": truncnorm_init(ks[3], (mc.n_experts, mc.expert_ff, d), dtype),
+        "w_gate": truncnorm_init(ks[1], (mc.held, d, mc.expert_ff), dtype),
+        "w_up": truncnorm_init(ks[2], (mc.held, d, mc.expert_ff), dtype),
+        "w_down": truncnorm_init(ks[3], (mc.held, mc.expert_ff, d), dtype),
     }
+    if mc.scoring == "sigmoid":
+        # DeepSeek-V3's e_score_correction_bias: steers selection only
+        p["select_bias"] = jnp.zeros((mc.n_experts,), jnp.float32)
     if mc.shared_ff:
         p["shared"] = swiglu_init(ks[4], d, mc.shared_ff, dtype)
     return p
@@ -381,34 +418,134 @@ def moe_specs(cfg):
         "w_up": ("experts", "expert_d", "moe_ff"),
         "w_down": ("experts", "moe_ff", "expert_d"),
     }
+    if cfg.moe.scoring == "sigmoid":
+        s["select_bias"] = (None,)
     if cfg.moe.shared_ff:
         s["shared"] = swiglu_specs()
     return s
 
 
+def moe_route(logits, mc, select_bias=None):
+    """Router logits (T, E) f32 → (gates (T, k) f32, experts (T, k) int32).
+
+    ``softmax``: softmax over all E, then the top k. ``sigmoid`` (DeepSeek-V3
+    noaux_tc): s = sigmoid(logits); experts are chosen by s + select_bias:
+    each of ``n_group`` groups scores the sum of its two best biased scores,
+    the best ``topk_group`` groups stay, and the top k of their experts are
+    taken; the gates are the unbiased s of those. Ties go to the lower
+    index (``lax.top_k``). Then the optional normalisation to sum 1, and
+    the ``routed_scaling_factor``."""
+    k = mc.top_k
+    if mc.scoring == "softmax":
+        gates, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    elif mc.scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        choice = s + select_bias
+        if mc.n_group > 1:
+            T, E = choice.shape
+            groups = choice.reshape(T, mc.n_group, E // mc.n_group)
+            group_score = jax.lax.top_k(groups, 2)[0].sum(-1)  # (T, n_group)
+            _, best = jax.lax.top_k(group_score, mc.topk_group)
+            kept = jnp.any(best[:, :, None] == jnp.arange(mc.n_group), axis=1)
+            choice = jnp.where(jnp.repeat(kept, E // mc.n_group, axis=1), choice, -jnp.inf)
+        _, eidx = jax.lax.top_k(choice, k)
+        gates = jnp.take_along_axis(s, eidx, axis=1)
+    else:
+        raise ValueError(f"unknown MoE scoring {mc.scoring!r}")
+    if mc.norm_topk_prob:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return gates * mc.routed_scaling_factor, eidx.astype(jnp.int32)
+
+
+def _router_logits(params, xt):
+    return xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)
+
+
+def moe_dropless(params, x, cfg, ctx=NO_CTX):
+    """Routed experts without a capacity: returns ``(out (B,S,d), aux,
+    pairs (held,) int32)``, ``pairs`` the (token, expert) pairs each held
+    expert computed, ``aux`` as :func:`moe_block`'s.
+
+    The router scores all ``n_experts``; the pairs whose expert lies in the
+    held share are sorted by expert and run through three grouped products
+    (``jax.lax.ragged_dot``), so the expert FLOPs follow the held pairs, and
+    every such pair is computed. Pairs of experts held elsewhere add
+    nothing here. The shared expert runs on every token."""
+    mc = cfg.moe
+    B, S, d = x.shape
+    T, k, H = B * S, mc.top_k, mc.held
+    xt = x.reshape(T, d)
+    logits = _router_logits(params, xt)
+    gates, eidx = moe_route(logits, mc, params.get("select_bias"))
+    local = eidx.reshape(-1) - mc.held_first
+    held = (local >= 0) & (local < H)
+    key = jnp.where(held, local, H)  # pairs held elsewhere sort last
+    pairs = jnp.zeros((H + 1,), jnp.int32).at[key].add(1)[:H]
+    # a token picks an expert at most once, so at most min(k, H) of its
+    # pairs are held here: the sorted prefix of that length holds them all
+    M = T * min(k, H)
+    order = jnp.argsort(key, stable=True)[:M]
+    tok = order // k
+    xs = xt[tok]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, params["w_gate"], pairs)) * jax.lax.ragged_dot(
+        xs, params["w_up"], pairs
+    )
+    y = jax.lax.ragged_dot(h.astype(x.dtype), params["w_down"], pairs)
+    live = jnp.arange(M) < pairs.sum()
+    g = jnp.where(live, gates.reshape(-1)[order], 0.0)
+    contrib = jnp.where(live[:, None], y.astype(jnp.float32) * g[:, None], 0.0)
+    out = jnp.zeros((T, d), jnp.float32).at[tok].add(contrib)
+    out = out.astype(x.dtype).reshape(B, S, d)
+    if mc.shared_ff:
+        out = out + swiglu(params["shared"], x, ctx)
+    aux = _balance_aux(logits, eidx, mc)
+    return ctx.cons(out, ("batch", "seq", "d_model")), aux, pairs
+
+
+def moe_forward(params, x, cfg, ctx=NO_CTX):
+    """The training forward's expert layer, ``(out, aux)``: the capacity
+    path where every expert is held here, else the dropless one."""
+    if cfg.moe.held == cfg.moe.n_experts:
+        return moe_block(params, x, cfg, ctx)
+    out, aux, _ = moe_dropless(params, x, cfg, ctx)
+    return out, aux
+
+
+def _balance_aux(logits, eidx, mc):
+    """The Switch load-balance loss E · Σ_e f_e · p_e over the router's E
+    experts: f_e the share of pairs routed to e, p_e the mean routing
+    probability — the softmax, or for sigmoid scoring the scores normalised
+    over the experts (DeepSeek-V3's sequence-wise form)."""
+    E = mc.n_experts
+    if mc.scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        me = (s / s.sum(-1, keepdims=True)).mean(0)
+    else:
+        me = jax.nn.softmax(logits, axis=-1).mean(0)
+    ce = jnp.zeros((E,), jnp.float32).at[eidx.reshape(-1)].add(1.0) / eidx.size
+    return E * jnp.sum(me * ce)
+
+
 def moe_block(params, x, cfg, ctx=NO_CTX):
-    """Top-k routed experts with capacity-factor sort-based dispatch.
+    """Top-k routed experts with capacity-factor sort-based dispatch (the
+    training forward). Returns ``(out, aux)``.
 
     Gathers/scatters (O(T·k·d) bytes, ~0 FLOPs) move tokens into per-expert
     buffers of capacity C = ceil(T·k/E · capacity_factor); expert matmuls
     are dense (E, C, d)×(E, d, f) einsums — compiled FLOPs stay proportional
     to ACTIVE parameters (MODEL_FLOPS ratio in the roofline stays honest).
-    Overflowing tokens are dropped (standard GShard/Switch semantics).
-    """
+    Overflowing tokens are dropped (standard GShard/Switch semantics), so
+    serving uses :func:`moe_dropless`; a held share has only that path.
+    ``aux`` is :func:`_balance_aux`."""
     mc = cfg.moe
+    if mc.held != mc.n_experts:
+        raise ValueError(f"{cfg.name}: a held share of experts serves only dropless")
     B, S, d = x.shape
     T = B * S
     E, k = mc.n_experts, mc.top_k
     xt = x.reshape(T, d)
-    logits = (xt.astype(jnp.float32) @ params["router"].astype(jnp.float32))
-    if mc.router_softmax_topk:  # softmax-then-topk (Switch/Mixtral style)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, eidx = jax.lax.top_k(probs, k)  # (T, k)
-    else:  # topk-then-softmax (DeepSeek style normalization)
-        gate_logits, eidx = jax.lax.top_k(logits, k)
-        gate_vals = jax.nn.softmax(gate_logits, axis=-1)
-    if mc.norm_topk_prob:
-        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    logits = _router_logits(params, xt)
+    gate_vals, eidx = moe_route(logits, mc, params.get("select_bias"))
 
     C = int(math.ceil(T * k / E * mc.capacity_factor))
     C = max(C, 4)
@@ -461,11 +598,7 @@ def moe_block(params, x, cfg, ctx=NO_CTX):
     out = out.astype(x.dtype).reshape(B, S, d)
     if mc.shared_ff:
         out = out + swiglu(params["shared"], x, ctx)
-    # load-balance aux loss (Switch): E * Σ_e f_e · p_e
-    me = jax.nn.softmax(logits, axis=-1).mean(0)
-    ce = jnp.zeros((E,), jnp.float32).at[flat_e].add(1.0) / (T * k)
-    aux = E * jnp.sum(me * ce)
-    return ctx.cons(out, ("batch", "seq", "d_model")), aux
+    return ctx.cons(out, ("batch", "seq", "d_model")), _balance_aux(logits, eidx, mc)
 
 
 def _segment_rank(same_as_prev):

@@ -5,6 +5,12 @@ attention. Decode: ABSORBED form — q_nope is folded through W_uk so scores
 are taken directly against the cached 512-dim latent (plus the shared rope
 key), and the output is reconstructed through W_uv. The cache holds only
 (c_kv: kv_lora_rank, k_rope: qk_rope_head_dim) per token — MLA's point.
+
+With ``cfg.yarn`` the rotary frequencies are YaRN's and the softmax scale
+is (1/sqrt(qk head dim)) · mscale², as DeepSeek-V3 publishes. The rotary
+part rotates the two halves of its 64 dimensions (``apply_rope``), where
+DeepSeek's inference code rotates adjacent pairs: the same map up to a
+fixed permutation of the rope columns of ``w_uq`` and ``w_kr``.
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ from .layers import (
     rmsnorm_init,
     rope_angles,
     truncnorm_init,
+    yarn_mscale,
 )
 
 
@@ -69,11 +76,19 @@ def _mla_qkr(params, x, cfg, positions):
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
     c_kv = rmsnorm(params["kv_norm"], x @ params["w_dkv"])
     k_rope = (x @ params["w_kr"]).reshape(B, S, 1, m.qk_rope_head_dim)
-    cos, sin = rope_angles(positions, m.qk_rope_head_dim, cfg.rope_theta)
+    cos, sin = rope_angles(positions, m.qk_rope_head_dim, cfg.rope_theta, cfg.yarn)
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
     q_rope = apply_rope(q_rope, cos, sin)
     k_rope = apply_rope(k_rope, cos, sin)
     return q_nope, q_rope, c_kv, k_rope
+
+
+def softmax_scale(cfg) -> float:
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if cfg.yarn is not None:
+        scale *= yarn_mscale(cfg.yarn.factor) ** 2
+    return scale
 
 
 def mla_fwd(params, x, cfg, ctx=NO_CTX, positions=None):
@@ -102,6 +117,10 @@ def mla_fwd(params, x, cfg, ctx=NO_CTX, positions=None):
         q_full.transpose(0, 2, 1, 3),
         k_full.transpose(0, 2, 1, 3),
         jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q_full.shape[-1] - m.v_head_dim))).transpose(0, 2, 1, 3),
+        scale=softmax_scale(cfg),
+        # blocks of 256 queries: at 128 heads a block's f32 scores are
+        # 128 MB, not 512, beside the decode cache a prefill writes into
+        chunk_q=256,
     )
     o = o.transpose(0, 2, 1, 3)[..., : m.v_head_dim].reshape(B, S, -1)
     y = o @ params["wo"]
@@ -125,7 +144,7 @@ def mla_decode(params, x, cfg, cache, pos, ctx=NO_CTX):
     ) + jnp.einsum(
         "bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32), krp.astype(jnp.float32)
     )
-    s = s / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    s = s * softmax_scale(cfg)
     mask = jnp.arange(Smax)[None, :] <= pos[:, None]
     s = jnp.where(mask[:, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)

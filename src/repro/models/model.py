@@ -39,7 +39,8 @@ from . import ssm as SSM
 # ---------------------------------------------------------------------------
 # layer-kind registry
 # ---------------------------------------------------------------------------
-# kind → (init, specs, fwd, decode, cache_init, cache_specs)
+# kind → (init, specs, fwd, decode, cache_init, cache_specs); a decode
+# returns (x, cache, pairs): pairs (held,) int32 from an expert layer, else None
 
 
 def _dense_init(key, cfg, dtype, d_ff=None):
@@ -74,7 +75,7 @@ def _dense_decode(params, x, cfg, cache, pos, ctx):
     )
     x = x + h
     x = x + L.swiglu(params["mlp"], L.rmsnorm(params["ln2"], x), ctx)
-    return x, cache2
+    return x, cache2, None
 
 
 def _dense_prefill(params, x, cfg, ctx, aux):
@@ -132,7 +133,7 @@ def _moe_fwd(params, x, cfg, ctx, aux):
     h, _ = L.attention_fwd(params["attn"], L.rmsnorm(params["ln1"], x), cfg, ctx)
     x = x + h
     xn = L.rmsnorm(params["ln2"], x)
-    mo, a = L.moe_block(params["moe"], xn, cfg, ctx)
+    mo, a = L.moe_forward(params["moe"], xn, cfg, ctx)
     if cfg.moe.dense_residual_ff:
         mo = mo + L.swiglu(params["dense_mlp"], xn, ctx)
     return x + mo, aux + a
@@ -144,17 +145,17 @@ def _moe_decode(params, x, cfg, cache, pos, ctx):
     )
     x = x + h
     xn = L.rmsnorm(params["ln2"], x)
-    mo, _ = L.moe_block(params["moe"], xn, cfg, ctx)
+    mo, _, pairs = L.moe_dropless(params["moe"], xn, cfg, ctx)
     if cfg.moe.dense_residual_ff:
         mo = mo + L.swiglu(params["dense_mlp"], xn, ctx)
-    return x + mo, cache2
+    return x + mo, cache2, pairs
 
 
 def _moe_prefill(params, x, cfg, ctx, aux):
     h, (k, v) = L.attention_fwd(params["attn"], L.rmsnorm(params["ln1"], x), cfg, ctx)
     x = x + h
     xn = L.rmsnorm(params["ln2"], x)
-    mo, a = L.moe_block(params["moe"], xn, cfg, ctx)
+    mo, a, _ = L.moe_dropless(params["moe"], xn, cfg, ctx)
     if cfg.moe.dense_residual_ff:
         mo = mo + L.swiglu(params["dense_mlp"], xn, ctx)
     return x + mo, aux + a, {"k": k, "v": v}
@@ -199,7 +200,7 @@ def _mla_fwd(moe: bool):
         x = x + h
         xn = L.rmsnorm(params["ln2"], x)
         if moe:
-            mo, a = L.moe_block(params["moe"], xn, cfg, ctx)
+            mo, a = L.moe_forward(params["moe"], xn, cfg, ctx)
             return x + mo, aux + a
         return x + L.swiglu(params["mlp"], xn, ctx), aux
 
@@ -214,9 +215,9 @@ def _mla_decode(moe: bool):
         x = x + h
         xn = L.rmsnorm(params["ln2"], x)
         if moe:
-            mo, _ = L.moe_block(params["moe"], xn, cfg, ctx)
-            return x + mo, cache2
-        return x + L.swiglu(params["mlp"], xn, ctx), cache2
+            mo, _, pairs = L.moe_dropless(params["moe"], xn, cfg, ctx)
+            return x + mo, cache2, pairs
+        return x + L.swiglu(params["mlp"], xn, ctx), cache2, None
 
     return dec
 
@@ -230,7 +231,7 @@ def _mla_prefill(moe: bool):
         xn = L.rmsnorm(params["ln2"], x)
         content = {"c_kv": c_kv, "k_rope": k_rope}
         if moe:
-            mo, a = L.moe_block(params["moe"], xn, cfg, ctx)
+            mo, a, _ = L.moe_dropless(params["moe"], xn, cfg, ctx)
             return x + mo, aux + a, content
         return x + L.swiglu(params["mlp"], xn, ctx), aux, content
 
@@ -273,7 +274,7 @@ def _mamba_fwd(moe: bool):
         x = x + h
         xn = L.rmsnorm(params["ln2"], x)
         if moe:
-            mo, a = L.moe_block(params["moe"], xn, cfg, ctx)
+            mo, a = L.moe_forward(params["moe"], xn, cfg, ctx)
             return x + mo, aux + a
         return x + L.swiglu(params["mlp"], xn, ctx), aux
 
@@ -286,9 +287,9 @@ def _mamba_decode(moe: bool):
         x = x + h
         xn = L.rmsnorm(params["ln2"], x)
         if moe:
-            mo, _ = L.moe_block(params["moe"], xn, cfg, ctx)
-            return x + mo, st
-        return x + L.swiglu(params["mlp"], xn, ctx), st
+            mo, _, pairs = L.moe_dropless(params["moe"], xn, cfg, ctx)
+            return x + mo, st, pairs
+        return x + L.swiglu(params["mlp"], xn, ctx), st, None
 
     return dec
 
@@ -328,7 +329,7 @@ def _rwkv_decode(params, x, cfg, cache, pos, ctx):
     x = x + h
     xn2 = L.layernorm(params["ln2"], x)
     h2, cm_prev = SSM.rwkv6_channel_mix(params["cm"], xn2, x_prev=cache["cm_prev"], return_state=True)
-    return x + h2, {"wkv": wkv, "tm_prev": tm_prev, "cm_prev": cm_prev}
+    return x + h2, {"wkv": wkv, "tm_prev": tm_prev, "cm_prev": cm_prev}, None
 
 
 _KINDS: dict[str, dict[str, Any]] = {
@@ -712,8 +713,12 @@ class Model:
             dims["enc_out"] = ("batch", "frames", "d_model")
         return dims
 
-    def decode_step(self, params, cache, tokens, pos, ctx=L.NO_CTX):
-        """tokens: (B,1) int32; pos: (B,) int32 → (logits (B,1,V), new cache)."""
+    def decode_step(self, params, cache, tokens, pos, ctx=L.NO_CTX, with_pairs=False):
+        """tokens: (B,1) int32; pos: (B,) int32 → (logits (B,1,V), new cache).
+
+        ``with_pairs`` also returns the (token, expert) pairs each held
+        expert computed, summed over the layers ((held,) int32; None for a
+        model without experts)."""
         cfg = self.cfg
         cache = dict(cache)
         x = self._embed(params, tokens).astype(self.dtype)
@@ -721,10 +726,12 @@ class Model:
             ppos = _sinusoidal_at(pos, cfg.d_model).astype(x.dtype)
             x = x + ppos[:, None, :]
         enc_out = cache.get("enc_out") if self.is_encdec else None
+        pairs = None
         for i, kind in enumerate(self.prefix):
-            x, cache[f"prefix_{i}"] = _KINDS[kind]["decode"](
+            x, cache[f"prefix_{i}"], p = _KINDS[kind]["decode"](
                 params[f"prefix_{i}"], x, cfg, cache[f"prefix_{i}"], pos, ctx
             )
+            pairs = _add_pairs(pairs, p)
         dec_fns = [_KINDS[k]["decode"] for k in self.body]
         cross_params = params["encoder"]["cross"] if self.is_encdec else None
 
@@ -732,19 +739,27 @@ class Model:
             x, li = carry
             blk, bcache = xs
             new_bcache = {}
+            step_pairs = None
             for j, fn in enumerate(dec_fns):
-                x, new_bcache[f"b{j}"] = fn(blk[f"b{j}"], x, cfg, bcache[f"b{j}"], pos, ctx)
+                x, new_bcache[f"b{j}"], p = fn(blk[f"b{j}"], x, cfg, bcache[f"b{j}"], pos, ctx)
+                step_pairs = _add_pairs(step_pairs, p)
                 if cross_params is not None:
                     cp = jax.tree.map(
                         lambda a: jax.lax.dynamic_index_in_dim(a, li * len(dec_fns) + j, keepdims=False),
                         cross_params,
                     )
                     x = x + self._cross_attn(cp, x, enc_out, cfg, ctx)
-            return (x, li + 1), new_bcache
+            return (x, li + 1), (new_bcache, step_pairs)
 
-        (x, _), new_body = jax.lax.scan(step, (x, 0), (params["body"], cache["body"]))
+        (x, _), (new_body, body_pairs) = jax.lax.scan(
+            step, (x, 0), (params["body"], cache["body"])
+        )
         cache["body"] = new_body
         logits = self._head(params, L.rmsnorm(params["ln_f"], x))
+        if with_pairs:
+            if body_pairs is not None:
+                pairs = _add_pairs(pairs, body_pairs.sum(0))
+            return logits, cache, pairs
         return logits, cache
 
     def prefill(self, params, batch, ctx=L.NO_CTX):
@@ -804,6 +819,10 @@ class Model:
         cache["body"] = new_body
         logits = self._head(params, L.rmsnorm(params["ln_f"], x))
         return logits, cache
+
+
+def _add_pairs(a, b):
+    return b if a is None else a if b is None else a + b
 
 
 def _xent(logits, labels, mask):

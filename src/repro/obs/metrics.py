@@ -22,6 +22,8 @@ repo:
   set), decode ticks, per-request time-to-first-token and end-to-end
   latency histograms, and the mean occupied-slot fraction; with
   ``tracer=`` also ``serve.prefill_us`` / ``serve.decode_chunk_us``;
+* ``models.moe.held_pairs`` — the (token, expert) pairs an MoE model's
+  held experts computed in the continuous engine's decode ticks;
 * ``serve.snapshots`` / ``serve.recoveries`` / ``serve.recovery_us`` —
   coded straggler-tolerant serving (``serve.coded.CodedServeGuard``):
   LCC snapshots of the decode-path state taken per chunk, hosts

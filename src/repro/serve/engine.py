@@ -28,13 +28,18 @@ Observability (``repro.obs``): ``serve.steps`` / ``serve.generate_ms`` /
 ``serve.tokens_per_s`` (generated-tokens-only in BOTH engines) /
 ``serve.eos_syncs_saved`` on the fixed path; ``serve.prefill_compiles``
 / ``serve.decode_steps`` / ``serve.ttft_ms`` / ``serve.e2e_ms`` /
-``serve.slot_occupancy`` on the continuous path. Passing ``tracer=``
+``serve.slot_occupancy`` on the continuous path, and for an MoE model
+``models.moe.held_pairs``: the (token, expert) pairs its held experts
+computed, counted on the device by the tick and fetched with the chunk's
+active mask. Passing ``tracer=``
 feeds the ``serve.step_us`` / ``serve.prefill_us`` /
 ``serve.decode_chunk_us`` latency histograms and, on the continuous path,
 puts every phase of a serve-loop iteration under a top-level span:
 ``serve.admit`` (``admitted``; one ``serve.prefill`` child per request),
-the guard's ``serve.snapshot``, ``serve.decode_chunk`` (``replay=1`` for
-the chunk replayed after a recovery), ``serve.poll``, the guard's
+the guard's ``serve.snapshot``, ``serve.decode_chunk`` (``cache_rows``, the
+occupied slots' positions summed at the chunk's start; ``moe_pairs`` and
+``moe_pairs_max`` for an MoE model; ``replay=1`` for the chunk replayed
+after a recovery), ``serve.poll``, the guard's
 ``serve.recovery``, ``serve.harvest`` (``finished``), and
 ``serve.wait`` while no slot is busy and the next arrival is due later.
 The fixed path's step spans force a device sync per step (opt-in); the
@@ -285,6 +290,8 @@ class ContinuousEngine:
         self._metrics = metrics
         self._prefill_jits: dict = {}  # (bucket, greedy) -> jitted graph
         self._tick_jits: dict = {}  # greedy -> jitted decode tick
+        #: an expert model's ticks count the pairs its held experts compute
+        self._moe_held = model.cfg.moe.held if model.cfg.moe is not None else 0
 
     # -- observability ------------------------------------------------------
     def _registry(self):
@@ -313,12 +320,12 @@ class ContinuousEngine:
         return tick
 
     def _make_tick(self, greedy: bool):
-        decode = make_decode_step(self.model, self._mesh, self._rules)
+        decode = make_decode_step(self.model, self._mesh, self._rules, with_pairs=True)
         V = self.model.cfg.vocab_size
         G = self.max_new_tokens
 
         def tick(params, cache, state, eos_id, temperature):
-            logits, cache = decode(
+            logits, cache, pairs = decode(
                 params, cache, state["last_tok"][:, None], state["pos"]
             )
             lg = logits[:, 0, :V]
@@ -343,7 +350,7 @@ class ContinuousEngine:
             pos = state["pos"] + active.astype(jnp.int32)
             hit_eos = active & (eos_id >= 0) & (nxt == eos_id)
             active = active & ~hit_eos & (gc < state["max_gen"])
-            state = {
+            new = {
                 "last_tok": nxt,
                 "pos": pos,
                 "active": active,
@@ -352,7 +359,9 @@ class ContinuousEngine:
                 "max_gen": state["max_gen"],
                 "rng": state["rng"],
             }
-            return cache, state
+            if pairs is not None:  # every slot's token went through the experts
+                new["moe_pairs"] = state["moe_pairs"] + pairs
+            return cache, new
 
         return jax.jit(tick, donate_argnums=(1, 2))
 
@@ -385,6 +394,7 @@ class ContinuousEngine:
             done = ((eos_id >= 0) & (t0 == eos_id)) | (req_max <= 1)
             row = jnp.zeros((G,), jnp.int32).at[0].set(t0)
             state = {
+                **state,
                 "last_tok": state["last_tok"].at[slot].set(t0),
                 "pos": state["pos"].at[slot].set(plen),
                 "active": state["active"].at[slot].set(~done),
@@ -412,6 +422,9 @@ class ContinuousEngine:
             "max_gen": jnp.zeros((S,), jnp.int32),
             "rng": jnp.zeros((S, 2), jnp.uint32),
         }
+        if self._moe_held:
+            # pairs each held expert computed in the ticks so far
+            state["moe_pairs"] = jnp.zeros((self._moe_held,), jnp.int32)
         return cache, state
 
     def _validate(self, req: Request) -> None:
@@ -472,7 +485,9 @@ class ContinuousEngine:
         if guard is not None:
             guard.attach(reg, tracer)
         meta: dict[int, tuple[Request, float]] = {}  # slot -> (req, ttft_s)
+        admitted_at: dict[int, int] = {}  # slot -> decode_steps at its prefill
         results: dict[str, RequestResult] = {}
+        pairs_seen = np.zeros((self._moe_held,), np.int64)
         ticks_active = ticks_total = decode_steps = 0
         t0 = time.perf_counter()
 
@@ -482,7 +497,21 @@ class ContinuousEngine:
         def run_chunk(cache, state):
             for _ in range(sync_every):
                 cache, state = tick(self.params, cache, state, eos, temp)
-            return cache, state, np.asarray(state["active"])
+            if self._moe_held:
+                # the expert counters come back with the chunk's one sync
+                active, pairs = jax.device_get((state["active"], state["moe_pairs"]))
+                return cache, state, active, pairs
+            return cache, state, np.asarray(state["active"]), None
+
+        def note_pairs(sp, pairs_now):
+            """The chunk's pairs on held experts: a span attribute and a counter."""
+            if pairs_now is None:
+                return
+            chunk = pairs_now - pairs_seen
+            reg.counter("models.moe.held_pairs").inc(int(chunk.sum()))
+            if sp is not None:
+                sp.attrs["moe_pairs"] = int(chunk.sum())
+                sp.attrs["moe_pairs_max"] = int(chunk.max())
 
         while sched.has_work:
             # 1. refill free slots with every arrived request (mid-decode
@@ -513,6 +542,7 @@ class ContinuousEngine:
                         reg.histogram("serve.prefill_us").observe(sp.dur_us)
                     ttft = now() - req.arrival_s
                     meta[slot] = (req, ttft)
+                    admitted_at[slot] = decode_steps
                     reg.histogram("serve.ttft_ms").observe(ttft * 1e3)
                     admitted += 1
                 if admit_sp is not None:
@@ -533,10 +563,14 @@ class ContinuousEngine:
             #    so a host lost mid-chunk costs one reconstruct + replay.
             if guard is not None:
                 guard.snapshot(cache, state, tick=decode_steps)
+            # cache rows the occupied slots hold at the chunk's start: each
+            # slot has run every tick since its prefill wrote its prompt
+            rows = sum(len(meta[s][0].prompt) + decode_steps - admitted_at[s] for s in occ)
             with self._span(
-                "serve.decode_chunk", ticks=sync_every, occupied=len(occ)
+                "serve.decode_chunk", ticks=sync_every, occupied=len(occ), cache_rows=rows
             ) as sp:
-                cache, state, active_now = run_chunk(cache, state)
+                cache, state, active_now, pairs_now = run_chunk(cache, state)
+                note_pairs(sp, pairs_now)
             if sp is not None:
                 reg.histogram("serve.decode_chunk_us").observe(sp.dur_us)
             decode_steps += sync_every
@@ -554,9 +588,12 @@ class ContinuousEngine:
                     )
                     with self._span(
                         "serve.decode_chunk", ticks=sync_every,
-                        occupied=len(occ), replay=1,
-                    ):
-                        cache, state, active_now = run_chunk(cache, state)
+                        occupied=len(occ), cache_rows=rows, replay=1,
+                    ) as sp:
+                        cache, state, active_now, pairs_now = run_chunk(cache, state)
+                        note_pairs(sp, pairs_now)
+            if pairs_now is not None:
+                pairs_seen = pairs_now
             # 3. harvest + retire finished slots (they refill next iteration)
             finished = [s for s in occ if not active_now[s]]
             with self._span("serve.harvest", finished=len(finished)):
@@ -565,6 +602,7 @@ class ContinuousEngine:
                     gen_buf = np.asarray(state["gen_buf"])
                     for s in finished:
                         req, ttft = meta.pop(s)
+                        del admitted_at[s]
                         sched.retire(s)
                         g = int(gen_counts[s])
                         e2e = now() - req.arrival_s

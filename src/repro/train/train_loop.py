@@ -62,11 +62,13 @@ def make_train_step(model, opt_cfg: opt.OptConfig, mesh=None, rules=None, accum:
     return train_step
 
 
-def make_decode_step(model, mesh=None, rules=None):
+def make_decode_step(model, mesh=None, rules=None, with_pairs=False):
+    """``(params, cache, tokens, pos) → (logits, cache)``; with
+    ``with_pairs`` also the held experts' pairs (``Model.decode_step``)."""
     ctx = make_ctx(mesh, rules)
 
     def decode_step(params, cache, tokens, pos):
-        return model.decode_step(params, cache, tokens, pos, ctx)
+        return model.decode_step(params, cache, tokens, pos, ctx, with_pairs=with_pairs)
 
     return decode_step
 

@@ -80,6 +80,9 @@ PUBLIC_SIZES = {
     "internlm2-20b": (19.9e9, 19.9e9, 0.05),
     "arctic-480b": (480e9, 17e9, 0.12),
     "deepseek-v3-671b": (671e9, 37e9, 0.05),
+    # one chip of EP32: 7 layers, 8 of 256 experts (all a token can reach
+    # here), a 16,384-row padded slice of the vocabulary
+    "deepseek-v3-ep32": (4.32e9, 4.32e9, 0.01),
     "rwkv6-3b": (3.0e9, 3.0e9, 0.08),
     "jamba-v0.1-52b": (52e9, 12e9, 0.05),
     "internvl2-26b": (20e9, 20e9, 0.05),  # LLM backbone only (ViT stubbed)

@@ -131,18 +131,19 @@ def test_lcc_decode_device_fits(one_chip):
     assert mem.temp_size_in_bytes < 1_000_000_000
 
 
-def _engine_program(one_chip, which: str, slots: int, max_len: int):
-    """The serving engine's own jitted decode tick or 256-token prefill for
-    qwen3-1.7b at its published widths, compiled from the shapes of the
-    engine's own start state placed on one described chip; with the bytes
-    of the weights and the decode state it must take as arguments."""
+def _engine_program(one_chip, which: str, slots: int, max_len: int,
+                    arch: str = "qwen3-1.7b", bucket: int = 256, max_new: int = 32):
+    """The serving engine's own jitted decode tick or ``bucket``-token
+    prefill for ``arch`` at its published widths, compiled from the shapes
+    of the engine's own start state placed on one described chip; with the
+    bytes of the weights and the decode state it must take as arguments."""
     from repro.configs import get
     from repro.models import build_model
     from repro.serve import ContinuousEngine
 
-    model = build_model(get("qwen3-1.7b"))
+    model = build_model(get(arch))
     eng = ContinuousEngine(model, None, n_slots=slots, max_len=max_len,
-                           max_new_tokens=32)
+                           buckets=(bucket,), max_new_tokens=max_new)
 
     def on_chip(tree):
         return jax.tree.map(
@@ -157,7 +158,6 @@ def _engine_program(one_chip, which: str, slots: int, max_len: int):
     if which == "decode":
         lowered = eng._tick_for(True).lower(params, cache, state, i32(), temp)
     else:
-        bucket = 256
         lowered = eng._prefill_for(bucket, True).lower(
             params, cache, state, i32(1, bucket), i32(), i32(), i32(), i32(),
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip), temp,
@@ -185,3 +185,20 @@ def test_qwen3_serving_program_fits_one_chip(one_chip, which, slots, max_len):
           f"{mem.output_size_in_bytes} B of which aliased {mem.alias_size_in_bytes} B")
     assert mem.argument_size_in_bytes >= held  # weights and the whole cache
     assert used < HBM_LIMIT_BYTES, (which, used)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_deepseek_ep32_serving_program_fits_one_chip(one_chip, which):
+    """One chip of EP32 DeepSeek-V3 (8.67 GB of weights) at the reasoning
+    cell's pool, 96 slots × 4096 (a 3.17 GB latent cache): the decode tick
+    and a 1024-token prefill leave at least 0.5 GB of the chip free. (At 128
+    slots the prefill left 0.47 GB.)"""
+    compiled, held = _engine_program(one_chip, which, 96, 4096, arch="deepseek-v3-ep32",
+                                     bucket=1024, max_new=3072)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"deepseek-v3-ep32 {which} 96x4096: arguments {mem.argument_size_in_bytes} B, "
+          f"temporaries {mem.temp_size_in_bytes} B, free {HBM_LIMIT_BYTES - used} B")
+    assert mem.argument_size_in_bytes >= held
+    assert used + 500_000_000 <= HBM_LIMIT_BYTES, (which, used)

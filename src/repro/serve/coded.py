@@ -23,12 +23,25 @@ deterministically —
 requests in flight on the dead host are **recovered, not dropped**, and
 the emitted token stream is bit-identical to an unfailed run.
 
+A snapshot copies to the host only what changed. The K data shards (the
+limbs) of the previous snapshot stay on the device; one compiled program
+compares the new ones with them block by block of :data:`SNAPSHOT_BLOCK`
+columns (coded column s is G · x[:, s], so a block whose limbs did not
+change has coded words that did not either), a compiled gather copies the
+changed blocks of the new coded array, and the hosts patch them into the
+shards they hold. Whole shards go, into fresh arrays, at the first snapshot
+of a shape and after every recovery.
+
 Observability: ``serve.recoveries`` (hosts recovered from), ``serve.
 recovery_us`` (reconstruction latency histogram), ``serve.snapshots``.
-With a tracer attached, each snapshot is a ``serve.snapshot`` span
-(``tick``) with children ``serve.snapshot.device`` (limbs + encode, up to
-the coded array being ready; ``words``), ``serve.snapshot.to_host``
-(``bytes``) and ``serve.snapshot.store`` (``shards``); each recovery is a
+``serve.snapshot_bytes_copied`` (bytes a snapshot copied to the host) and
+``serve.snapshots_full`` (snapshots that stored whole shards). With a tracer
+attached, each snapshot is a ``serve.snapshot`` span (``tick``) with
+children ``serve.snapshot.device`` (limbs, their comparison with the
+previous snapshot's, and the encode, up to the coded array being ready;
+``words``), ``serve.snapshot.to_host`` (``bytes`` copied, ``blocks``
+copied, ``full`` 1 when whole shards went) and ``serve.snapshot.store``
+(``shards``: hosts stored or patched); each recovery is a
 ``serve.recovery`` span (``hosts``, ``tick``) with children
 ``serve.recovery.fetch`` (``bytes``, ``responders``; the host shards),
 ``serve.recovery.decode`` (``words``; upload of the K survivors and the
@@ -39,6 +52,7 @@ device interpolation, up to ready) and ``serve.recovery.to_device``
 from __future__ import annotations
 
 import base64
+import collections
 import signal
 import subprocess
 import sys
@@ -48,6 +62,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from repro.coded.lagrange_compute import (
     build_lcc,
@@ -97,7 +112,7 @@ class FaultInjector:
 #: the whole host program: store one shard, serve it back on request. No
 #: repro imports — a host is just memory that can die.
 _HOST_LOOP = r"""
-import sys
+import base64, sys
 store = None
 for line in sys.stdin:
     line = line.strip()
@@ -105,10 +120,18 @@ for line in sys.stdin:
         continue
     cmd, _, arg = line.partition(" ")
     if cmd == "put":
-        store = arg
+        store = bytearray(base64.b64decode(arg))
         sys.stdout.write("ok\n")
     elif cmd == "get":
-        sys.stdout.write(("none" if store is None else store) + "\n")
+        sys.stdout.write(("none" if store is None else base64.b64encode(store).decode()) + "\n")
+    elif cmd == "patch" and store is not None:
+        # block k's words start at min(k * block, S - block)
+        block, ids, words = arg.split(" ")
+        size, ids, words = 4 * int(block), memoryview(base64.b64decode(ids)).cast("I"), base64.b64decode(words)
+        for i, k in enumerate(ids):
+            at = min(k * size, len(store) - size)
+            store[at:at + size] = words[i * size:(i + 1) * size]
+        sys.stdout.write("ok\n")
     elif cmd == "quit":
         break
     else:
@@ -155,6 +178,21 @@ class ProcessHostPool:
         except (BrokenPipeError, OSError, ValueError):
             return False
 
+    def patch(self, host: int, block_ids: np.ndarray, values: np.ndarray) -> bool:
+        """Overwrite blocks ``block_ids`` of the host's shard with ``values``
+        ((n, block) uint32), as :func:`write_blocks` does."""
+        p = self.procs[host]
+        if p.poll() is not None:
+            return False
+        ids = base64.b64encode(np.asarray(block_ids, "<u4").tobytes()).decode()
+        words = base64.b64encode(np.ascontiguousarray(values, "<u4").tobytes()).decode()
+        try:
+            p.stdin.write(f"patch {values.shape[-1]} {ids} {words}\n")
+            p.stdin.flush()
+            return p.stdout.readline().strip() == "ok"
+        except (BrokenPipeError, OSError, ValueError):
+            return False
+
     def fetch(self, host: int) -> np.ndarray | None:
         p = self.procs[host]
         if p.poll() is not None:
@@ -195,6 +233,113 @@ class ProcessHostPool:
 
 
 # ---------------------------------------------------------------------------
+# the delta copy: which blocks changed, and their coded words to the host
+# ---------------------------------------------------------------------------
+
+#: words in a column block, the unit in which a snapshot finds what changed
+#: and copies it. A decode chunk writes runs of a few thousand contiguous
+#: limbs (a few positions of one slot's K or V rows in one layer), so much
+#: larger blocks copy mostly unchanged words and much smaller ones multiply
+#: the flags and the gather's slices; a multiple of the TPU's 128 lanes.
+SNAPSHOT_BLOCK = 1024
+
+#: blocks one call of the compiled gather copies (4 MiB at N = 4): a decode
+#: chunk's changes take a few calls, the last padded by at most this many
+#: blocks, and a whole state a few hundred
+GATHER_BLOCKS = 256
+
+#: gather calls whose copies to the host run ahead of the host's reads
+_IN_FLIGHT = 8
+
+
+def _changed_blocks(x, base, *, block):
+    """(n_blocks,) bool: whether any row of the (rows, S) array ``x``
+    differs from ``base`` in each column block. The last block ends at S,
+    so it overlaps the one before when S is no multiple of ``block``."""
+    rows, S = x.shape
+    differs = x != base
+    whole = S // block
+    flags = differs[:, : whole * block].reshape(rows, whole, block).any(axis=(0, 2))
+    if S % block:
+        flags = jnp.append(flags, differs[:, S - block:].any())
+    return flags
+
+
+def _gather_blocks(coded, block_ids, *, block):
+    """(N, len(block_ids), block): the words of each listed column block."""
+    starts = jnp.minimum(block_ids * block, coded.shape[1] - block)
+    return jax.vmap(
+        lambda at: jax.lax.dynamic_slice_in_dim(coded, at, block, axis=1),
+        out_axes=1,
+    )(starts)
+
+
+_changed_blocks_jit = jax.jit(_changed_blocks, static_argnames="block")
+_gather_blocks_jit = jax.jit(_gather_blocks, static_argnames="block")
+
+
+def write_blocks(row: np.ndarray, block_ids: np.ndarray, values: np.ndarray) -> None:
+    """Write column block ``block_ids[i]`` of the (S,) array ``row`` from
+    ``values[i]`` ((n, block)), in place. ``block_ids`` ascend, so only the
+    last can be the block that ends at S."""
+    block = values.shape[-1]
+    whole = row.size // block
+    head = row[: whole * block].reshape(whole, block)
+    if block_ids[-1] < whole:
+        head[block_ids] = values
+    else:
+        head[block_ids[:-1]] = values[:-1]
+        row[-block:] = values[-1]
+
+
+class BlockCopy:
+    """The delta copy's programs for one state shape, compiled when made:
+    which column blocks of the (K, S) data shards changed against the
+    previous snapshot's, and those blocks of the (N, S) coded array
+    gathered on the device and copied to the host :attr:`per_call` blocks a
+    call."""
+
+    def __init__(self, shards: jax.Array, coded: jax.Array):
+        self.N, S = coded.shape
+        self.block = min(SNAPSHOT_BLOCK, S)
+        self.n_blocks = -(-S // self.block)
+        self.per_call = min(GATHER_BLOCKS, self.n_blocks)
+        self.all_ids = np.arange(self.n_blocks, dtype=np.int32)
+        _changed_blocks_jit.lower(shards, shards, block=self.block).compile()
+        _gather_blocks_jit.lower(
+            coded, np.zeros(self.per_call, np.int32), block=self.block
+        ).compile()
+
+    def changed(self, shards: jax.Array, base: jax.Array) -> np.ndarray:
+        """Ascending ids of the blocks where ``shards`` differ from ``base``."""
+        flags = _changed_blocks_jit(shards, base, block=self.block)
+        return np.flatnonzero(np.asarray(flags)).astype(np.int32)
+
+    def bytes_copied(self, n: int) -> int:
+        """Bytes the gather calls for ``n`` blocks copy, padding included."""
+        return -(-n // self.per_call) * self.per_call * self.N * self.block * 4
+
+    def fetch(self, coded: jax.Array, block_ids: np.ndarray):
+        """Yield (ids, (N, len(ids), block) host uint32 words) a gather call
+        at a time; the last call is padded with its last id."""
+        pending = collections.deque()
+        for at in range(0, len(block_ids), self.per_call):
+            ids = block_ids[at : at + self.per_call]
+            words = _gather_blocks_jit(
+                coded, np.pad(ids, (0, self.per_call - ids.size), mode="edge"),
+                block=self.block,
+            )
+            words.copy_to_host_async()
+            pending.append((ids, words))
+            if len(pending) == _IN_FLIGHT:
+                ids, words = pending.popleft()
+                yield ids, np.asarray(words)[:, : ids.size]
+        while pending:
+            ids, words = pending.popleft()
+            yield ids, np.asarray(words)[:, : ids.size]
+
+
+# ---------------------------------------------------------------------------
 # the K-of-N decode group
 # ---------------------------------------------------------------------------
 
@@ -204,7 +349,8 @@ class CodedDecodeGroup:
 
     A "host" is either an in-memory slot (default) or one
     :class:`ProcessHostPool` child process. The group hands coded shard j
-    to host j after each encode (host uint32 arrays), tracks which hosts
+    to host j (host uint32 arrays; :meth:`store` whole, :meth:`patch` the
+    blocks that changed), tracks which hosts
     are alive, and rebuilds all K data shards from the first K survivors
     via Lagrange interpolation on the device
     (``repro.coded.lcc_decode_device``).
@@ -224,7 +370,8 @@ class CodedDecodeGroup:
         self.tracer = None
 
     def store(self, coded: np.ndarray) -> None:
-        """Hand coded row j to host j; a host found dead mid-store is
+        """Hand coded row j to host j, in place of what it held (in-memory
+        hosts keep row j itself, not a copy); a host found dead mid-store is
         dropped from the alive set, not raised on."""
         self._mem = {}
         for j in sorted(self.alive):
@@ -233,6 +380,17 @@ class CodedDecodeGroup:
                     self.alive.discard(j)
             else:
                 self._mem[j] = np.asarray(coded[j], dtype=np.uint32)
+
+    def patch(self, block_ids: np.ndarray, values: np.ndarray) -> None:
+        """Overwrite column blocks ``block_ids`` of the shard host j holds
+        with ``values[j]`` ((N, n, block) uint32; :func:`write_blocks`), in
+        place; a host found dead mid-patch is dropped, not raised on."""
+        for j in sorted(self.alive):
+            if self.hosts is not None:
+                if not self.hosts.patch(j, block_ids, values[j]):
+                    self.alive.discard(j)
+            else:
+                write_blocks(self._mem[j], block_ids, values[j])
 
     def kill(self, host: int) -> bool:
         """Take host down (SIGKILL when it is a process). Returns whether
@@ -344,7 +502,10 @@ class CodedServeGuard:
 
             self._encode = jax.jit(coded_snapshot_encode)
         self._meta = None
-        self._decode_shapes: set[tuple[int, ...]] = set()
+        #: the (K, S) data shards whose encode the live hosts hold
+        self._base: jax.Array | None = None
+        #: by (K, S): the programs of the first snapshot of each shape
+        self._copies: dict[tuple[int, ...], BlockCopy] = {}
         self._tick = -1
         self._metrics = None
         self._tracer = None
@@ -371,33 +532,60 @@ class CodedServeGuard:
 
     def snapshot(self, cache, state, tick: int) -> None:
         """Encode the decode-path state ((cache, state) pytree → limbs →
-        K shards → N coded shards) and hand shard j to host j. The first
-        snapshot of a shard shape also compiles the recovery's decode for
-        it, so that a recovery compiles nothing."""
+        K shards → N coded shards) and bring host j's shard to coded row j:
+        the column blocks whose limbs differ from the previous snapshot's
+        are copied and patched in, or whole shards are stored, into
+        fresh arrays, at the first snapshot of a shape and after a
+        recovery. The first snapshot of a shape also compiles the
+        comparison, the gather and the recovery's decode for it, so that
+        later snapshots and a recovery compile nothing."""
         tracer = self._tracer
+        # dropped until the hosts hold the new coded array (a snapshot cut
+        # short leaves the next one to store whole shards), and freed before
+        # the encode, whose program needs the room
+        base, self._base = self._base, None
         with optional_span(tracer, "serve.snapshot", tick=tick):
             with optional_span(tracer, "serve.snapshot.device") as sp:
                 shards, meta = shard_state_limbs((cache, state), self.K)
+                copy = self._copies.get(shards.shape)
+                full = base is None or base.shape != shards.shape
+                ids = None if full else copy.changed(shards, base)
+                del base
                 coded = jax.block_until_ready(
                     self._encode(lcc_pad(self.plan, shards))
                 )
+                if copy is None:
+                    copy = self._copies[shards.shape] = BlockCopy(shards, coded)
+                    compile_lcc_decode_device(self.plan, shards.shape[1])
+                if full:
+                    ids = copy.all_ids
                 if sp is not None:
                     sp.attrs["words"] = int(shards.size)
-            with optional_span(
-                tracer, "serve.snapshot.to_host", bytes=coded.size * 4
-            ):
-                coded = np.asarray(coded, dtype=np.uint32)
+            copied = copy.bytes_copied(ids.size)
+            with optional_span(tracer, "serve.snapshot.to_host", bytes=copied,
+                               blocks=int(ids.size), full=int(full)):
+                if full:
+                    rows = np.empty(coded.shape, np.uint32)
+                    for part, words in copy.fetch(coded, ids):
+                        for j in range(self.N):
+                            write_blocks(rows[j], part, words[j])
+                else:
+                    parts = list(copy.fetch(coded, ids))
             self._meta, self._tick = meta, tick
             with optional_span(tracer, "serve.snapshot.store") as sp:
-                self.group.store(coded)
+                if full:
+                    self.group.store(rows)
+                else:
+                    for part, words in parts:
+                        self.group.patch(part, words)
                 if sp is not None:
                     sp.attrs["shards"] = len(self.group.alive)
-        if shards.shape not in self._decode_shapes:
-            compile_lcc_decode_device(self.plan, shards.shape[1])
-            self._decode_shapes.add(shards.shape)
+        self._base = shards
         self.snapshots += 1
         if self._metrics is not None:
             self._metrics.counter("serve.snapshots").inc()
+            self._metrics.counter("serve.snapshot_bytes_copied").inc(copied)
+            self._metrics.counter("serve.snapshots_full").inc(int(full))
 
     def poll(self, now_tick: int) -> list[int]:
         """Fire due injector kills (SIGKILL when hosts are processes) and
@@ -416,9 +604,12 @@ class CodedServeGuard:
         """Rebuild the chunk-start (cache, state) bit-exactly from any K
         surviving coded shards (Lagrange interpolation on the device, then
         the unshard into the engine's state). Raises RuntimeError
-        once fewer than K shards survive — beyond the code's tolerance."""
+        once fewer than K shards survive — beyond the code's tolerance.
+        The next snapshot stores whole shards into fresh arrays: an array a
+        host held at a recovery is never written again."""
         if self._meta is None:
             raise RuntimeError("no snapshot taken before recovery")
+        self._base = None
         tracer = self._tracer
         with optional_span(
             tracer, "serve.recovery", hosts=str(sorted(dead)), tick=self._tick

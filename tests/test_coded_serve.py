@@ -289,6 +289,150 @@ def test_recovery_after_a_snapshot_compiles_nothing():
         np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(state[k]))
 
 
+def test_later_snapshots_and_a_recovery_compile_nothing():
+    """After the first snapshot of a shape, snapshots that copy some, all
+    or none of the blocks, a recovery, and the whole-shard snapshot after
+    it compile nothing; the recovery gives the last snapshot's state."""
+    import jax.numpy as jnp
+
+    from repro.coded import shard_state_limbs, unshard_state_limbs
+
+    x = jnp.linspace(-3.0, 3.0, 20_011, dtype=jnp.float32)
+    n = jnp.arange(77, dtype=jnp.int32)
+    states = [{"x": x, "n": n}, {"x": x.at[:5].set(9.0), "n": n},
+              {"x": -x, "n": n + 1}, {"x": -x, "n": n + 1}]
+    tracer = Tracer()
+    guard = CodedServeGuard(K=3, R=1, injector=FaultInjector(kills=((0, 1),)))
+    guard.attach(MetricsRegistry(), tracer)
+    guard.snapshot({}, states[0], tick=0)
+    shards, meta = shard_state_limbs(({}, states[0]), 3)
+    unshard_state_limbs(jnp.asarray(np.zeros(shards.shape, np.uint32)), meta)
+    with _counting_compiles() as log:
+        for t, st_ in enumerate(states[1:], 1):
+            guard.snapshot({}, st_, tick=t)
+        dead = guard.poll(4)
+        _, back = guard.recover(dead)
+        guard.snapshot({}, states[0], tick=4)
+    assert dead == [1]
+    assert log.compiles == 0
+    copied = [sp.attrs for sp in tracer.spans if sp.name == "serve.snapshot.to_host"]
+    n_blocks = copied[0]["blocks"]
+    assert n_blocks > 4 and shards.shape[1] % n_blocks
+    assert [a["full"] for a in copied] == [1, 0, 0, 0, 1]
+    assert 0 < copied[1]["blocks"] < n_blocks
+    assert copied[2]["blocks"] == n_blocks and copied[3]["blocks"] == 0
+    for k in states[-1]:
+        np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(states[-1][k]))
+
+
+# ---------------------------------------------------------------------------
+# delta snapshots: the hosts' shards after every snapshot
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=1)
+def _long_engine():
+    """The smoke model with 1024 cache rows a slot: its coded state spans
+    many snapshot blocks, and a decode chunk writes few of them."""
+    cfg, model, params = _smoke()
+    return ContinuousEngine(
+        model, params, n_slots=2, max_len=1024, buckets=(8, 16),
+        max_new_tokens=MAX_NEW, metrics=MetricsRegistry(),
+    )
+
+
+@functools.lru_cache(maxsize=1)
+def _long_baseline():
+    return _toks(_long_engine().serve(_reqs(), greedy=True, sync_every=2, seed=0))
+
+
+def _serve_checked(guard, check):
+    """A traced serve of ``_reqs()`` on the long engine under ``guard``,
+    calling ``check(cache, state)`` after every snapshot; returns (tokens,
+    the registry's snapshot, the ``serve.snapshot.to_host`` spans' attrs)."""
+    eng = _long_engine()
+    reg, tracer = MetricsRegistry(), Tracer()
+    saved = eng._metrics, eng._tracer
+    snapshot = guard.snapshot
+
+    def checked(cache, state, tick):
+        snapshot(cache, state, tick)
+        check(cache, state)
+
+    guard.snapshot = checked
+    eng._metrics, eng._tracer = reg, tracer
+    try:
+        rep = eng.serve(_reqs(), greedy=True, sync_every=2, guard=guard)
+    finally:
+        eng._metrics, eng._tracer = saved
+    copied = [sp.attrs for sp in tracer.spans if sp.name == "serve.snapshot.to_host"]
+    return _toks(rep), reg.snapshot(), copied
+
+
+@pytest.mark.parametrize("hosts", ["memory", "processes"])
+def test_delta_snapshots_hold_the_full_encode(hosts):
+    """Through admissions, retirements, a kill and its replay, under a
+    state whose S is no multiple of the block: after every snapshot each
+    live host holds row j of the full encode of the snapshot's state, bit
+    for bit; decode-only snapshots copy part of the state (whole shards
+    go only at the first snapshot and after the recovery); the tokens
+    equal the unfailed run's."""
+    from repro.coded import lcc_encode, lcc_pad, shard_state_limbs
+    from repro.serve.coded import SNAPSHOT_BLOCK
+
+    with contextlib.ExitStack() as stack:
+        pool = (stack.enter_context(ProcessHostPool(4))
+                if hosts == "processes" else None)
+        guard = CodedServeGuard(
+            K=3, R=1, injector=FaultInjector(kills=((3, 1),)), hosts=pool
+        )
+        sizes = []
+
+        def check(cache, state):
+            shards, _ = shard_state_limbs((cache, state), 3)
+            want = np.asarray(lcc_encode(guard.plan, lcc_pad(guard.plan, shards)[:3]))
+            sizes.append(shards.shape[1])
+            for j in sorted(guard.alive):
+                held = pool.fetch(j) if pool is not None else guard.group._mem[j]
+                np.testing.assert_array_equal(held, want[j])
+
+        toks, snap, copied = _serve_checked(guard, check)
+    assert toks == _long_baseline()
+    assert guard.recoveries == 1 and sorted(guard.alive) == [0, 2, 3]
+    assert sizes[0] % SNAPSHOT_BLOCK and len(sizes) == guard.snapshots
+    assert snap["serve.snapshots_full"]["value"] == 2  # the first, after the recovery
+    assert snap["serve.snapshots_full"]["value"] < snap["serve.snapshots"]["value"]
+    whole = copied[0]["blocks"]
+    assert [a["full"] for a in copied].count(1) == 2
+    assert all(0 < a["blocks"] < whole for a in copied if not a["full"])
+    assert snap["serve.snapshot_bytes_copied"]["value"] == sum(a["bytes"] for a in copied)
+
+
+def test_arrays_held_at_a_recovery_are_never_written_again():
+    """A caller that keeps the arrays the hosts held at a recovery (as the
+    benchmark's check does) finds them as they were after more chunks: the
+    snapshot after a recovery stores into fresh arrays."""
+    guard = CodedServeGuard(K=3, R=1, injector=FaultInjector(kills=((1, 0),)))
+    recover = guard.recover
+    held = {}
+
+    def watched(dead, requests_in_flight=0):
+        held.update({j: (v, v.copy()) for j, v in guard.group._mem.items()})
+        held["snapshots"] = guard.snapshots
+        return recover(dead, requests_in_flight)
+
+    guard.recover = watched
+    toks, snap, _ = _serve_checked(guard, lambda cache, state: None)
+    assert toks == _long_baseline() and guard.recoveries == 1
+    at_recovery = held.pop("snapshots")
+    assert guard.snapshots >= at_recovery + 2  # a patched snapshot came after
+    assert snap["serve.snapshots_full"]["value"] == 2
+    assert sorted(held) == [0, 1, 2, 3]
+    for j, (ref, copy) in held.items():
+        np.testing.assert_array_equal(ref, copy)
+        assert all(not np.shares_memory(ref, v) for v in guard.group._mem.values())
+
+
 # ---------------------------------------------------------------------------
 # real host processes: SIGKILL mid-decode, 8 forced host devices
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ from repro.models import build_model
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer, optional_span
 from repro.serve import CodedServeGuard, ContinuousEngine, FaultInjector, Request
+from repro.serve.coded import GATHER_BLOCKS, SNAPSHOT_BLOCK
 
 PROMPTS = [[5, 9, 2, 7, 1], [3, 3, 8], [11, 4, 6, 2], [2]]
 MAX_NEW = 6
@@ -156,15 +157,25 @@ def test_coded_span_tree(parent, children, wrap_reconstruct):
 @pytest.mark.parametrize("wrap_reconstruct", [False, True], ids=["plain", "wrapped"])
 def test_coded_span_counts(wrap_reconstruct):
     """The counts on the spans: words K × S on the device and in the
-    decode, bytes N × S × 4 to the host, K × S × 4 fetched and back to
-    the device, one shard stored per live host."""
+    decode; to the host, the blocks copied and their bytes (N rows of
+    whole gather calls), every block at the first snapshot and at the one
+    after the recovery; K × S × 4 fetched and back to the device, one
+    shard stored per live host."""
     spans, toks, guard, seen = _traced(wrap_reconstruct)
     by = {}
     for sp in spans:
         by.setdefault(sp.name, []).append(sp.attrs)
     S = guard._meta.total // K + (guard._meta.total % K > 0)
     assert all(a["words"] == K * S for a in by["serve.snapshot.device"])
-    assert all(a["bytes"] == guard.N * S * 4 for a in by["serve.snapshot.to_host"])
+    block = min(SNAPSHOT_BLOCK, S)
+    n_blocks = -(-S // block)
+    per_call = min(GATHER_BLOCKS, n_blocks)
+    to_host = by["serve.snapshot.to_host"]
+    assert all(a["bytes"] == -(-a["blocks"] // per_call) * per_call * guard.N * block * 4
+               for a in to_host)
+    assert [a["full"] for a in to_host][:2] == [1, 1]  # the kill follows the first chunk
+    assert all(a["blocks"] == n_blocks for a in to_host if a["full"])
+    assert all(a["blocks"] <= n_blocks for a in to_host)
     assert [a["tick"] for a in by["serve.snapshot"]] == sorted(
         a["tick"] for a in by["serve.snapshot"])
     stored = [a["shards"] for a in by["serve.snapshot.store"]]

@@ -389,8 +389,9 @@ def coded_phase(cfg, model, params, mesh, rules, host_mesh=None) -> None:
 
 
 def cross_chip_encode_phase() -> None:
-    """ps_encode_jit on 4 and hierarchical_encode_jit on 2x2 with kernels=None:
-    a ``tpu_custom_call`` in the HLO shows it resolved to the Pallas kernels."""
+    """ps_encode_jit on 4 and hierarchical_encode_jit on 2x2, the lowering
+    chosen from the mesh: a ``tpu_custom_call`` in the HLO shows it resolved
+    to the Pallas kernels."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -137,7 +137,7 @@ def lcc_encode(plan: LCCPlan, X: jnp.ndarray) -> jnp.ndarray:
     return encode_universal(xp, lcc_generator(plan), p=plan.p, q=plan.q)
 
 
-def lcc_encode_collective(mesh, axis: str, plan: LCCPlan, **kw):
+def lcc_encode_collective(mesh, axis: str, plan: LCCPlan):
     """Mesh path: jitted (N, *payload) → (N, *payload) encode of the padded
     Lagrange generator, communication = ppermute rounds on ``axis`` (size N)
     — the prepare-and-shoot ScheduleIR executed through
@@ -155,7 +155,7 @@ def lcc_encode_collective(mesh, axis: str, plan: LCCPlan, **kw):
         raise ValueError(
             f"mesh axis {axis!r} has {K_axis} devices, need N={plan.N}"
         )
-    fn, _ = ps_encode_jit(mesh, axis, lcc_generator(plan), p=plan.p, q=plan.q, **kw)
+    fn, _ = ps_encode_jit(mesh, axis, lcc_generator(plan), p=plan.p, q=plan.q)
     rows = NamedSharding(mesh, PartitionSpec(axis))
     return lambda xp: fn(jax.device_put(xp, rows))
 

@@ -68,8 +68,8 @@ def shoot_init(
     """w[k, l] = Σ_u buf[k, u] · mask[u,l] · A[(k-off_u)%K, (k+l·m)%K] (mod q).
 
     This modular contraction is the gf_matmul kernel hot spot; here it is the
-    pure-jnp form (kernels/gf_matmul/ops.py provides the Pallas-backed drop-in
-    used by benchmarks).
+    pure-jnp form (kernels/gf_matmul/ops.py provides the Pallas-backed
+    drop-in).
     """
     mask = coeff_mask(plan)  # (m, n) bool
     npay = buf.ndim - 2

@@ -91,20 +91,12 @@ class SyncSimulator:
 
 
 def interpret(
-    ir: ScheduleIR, x: np.ndarray, field: Field, *, tracer=None, topo=None
+    ir: ScheduleIR, x: np.ndarray, field: Field
 ) -> tuple[np.ndarray, SimStats]:
     """Execute ``ir`` on input ``x`` (shape (K,), uint64 canonical mod q)
     under the p-port constraints; returns (output, stats). Inputs and
     outputs are in LOGICAL processor order — ``ir.placement`` (set by layout
-    passes like ``topo.passes.remap_digits``) is applied at the boundary.
-
-    ``tracer`` (a :class:`repro.obs.trace.Tracer`) opts into per-round
-    spans mirroring the mesh executor's instrumentation: one span per
-    CommRound with its round index, transfer count, and largest message
-    (host wall time here measures the interpreter itself, not a network —
-    useful for tracing schedule structure, not for calibration); ``topo``
-    (a :class:`repro.topo.model.Topology`) additionally stamps the α-β
-    model's ``predicted_us`` on each round span."""
+    passes like ``topo.passes.remap_digits``) is applied at the boundary."""
     K = ir.K
     x = field.asarray(np.asarray(x))
     if x.shape != (K,):
@@ -119,85 +111,53 @@ def interpret(
     buf: list[dict] = [{} for _ in range(K)]
     for k in range(K):
         buf[place[k]][INPUT_SLOT] = x[k]
-    from contextlib import nullcontext
-
-    root = (
-        tracer.span("interpret", algorithm=ir.algorithm, K=K, p=ir.p)
-        if tracer is not None
-        else nullcontext()
-    )
-    round_no = -1
-    with root:
-        for step in ir.steps:
-            if isinstance(step, CommRound):
-                validate_round(step)
-                round_no += 1
-                msgs: dict = {}
-                modes: dict = {}
-                for t in step.transfers:
-                    payload = []
-                    for i, (ss, ds) in enumerate(t.slots):
-                        c = t.coeffs[i] if t.coeffs is not None else 1
-                        payload.append((ds, c, buf[t.src].get(ss, zero)))
-                    msgs[(t.src, t.dst)] = payload
-                    modes[(t.src, t.dst)] = t.mode
-                span = nullcontext()
-                if tracer is not None:
-                    attrs = {
-                        "algorithm": ir.algorithm,
-                        "comm_round": round_no,
-                        "transfers": len(step.transfers),
-                        "slots": max(len(v) for v in msgs.values()),
-                        "payload_elems": 1,
-                    }
-                    if topo is not None:
-                        from repro.topo.model import schedule_time
-
-                        attrs["predicted_us"] = (
-                            schedule_time(
-                                topo, [{p_: len(v) for p_, v in msgs.items()}]
-                            ).total
-                            * 1e6
-                        )
-                    span = tracer.span(f"round[{round_no}]", **attrs)
-                with span:
-                    delivered = sim.exchange(msgs)
-                    for pair, payload in delivered.items():
-                        dst = pair[1]
-                        store = modes[pair] == "store"
-                        for ds, c, v in payload:
-                            if c != 1:
-                                v = field.mul(np.uint64(c), v)
-                            if store:
-                                buf[dst][ds] = v
-                            else:
-                                buf[dst][ds] = field.add(
-                                    buf[dst].get(ds, zero), v
-                                )
-            elif isinstance(step, LocalOp):
-                if step.coeffs is None:
-                    raise ValueError(
-                        "structure-only IR (LocalOp.coeffs=None) cannot be "
-                        "interpreted — recompile with the generator matrix"
-                    )
-                n_in = len(step.in_slots)
-                cols = np.zeros((K, n_in), dtype=np.uint64)
-                for j, s in enumerate(step.in_slots):
-                    for k in range(K):
-                        cols[k, j] = buf[k].get(s, zero)
-                out = np.zeros((K, len(step.out_slots)), dtype=np.uint64)
-                for j in range(n_in):
-                    out = field.add(
-                        out, field.mul(step.coeffs[:, :, j], cols[:, j][:, None])
-                    )
-                for k in range(K):
-                    if step.update:
-                        for i, s in enumerate(step.out_slots):
-                            buf[k][s] = out[k, i]
+    for step in ir.steps:
+        if isinstance(step, CommRound):
+            validate_round(step)
+            msgs: dict = {}
+            modes: dict = {}
+            for t in step.transfers:
+                payload = []
+                for i, (ss, ds) in enumerate(t.slots):
+                    c = t.coeffs[i] if t.coeffs is not None else 1
+                    payload.append((ds, c, buf[t.src].get(ss, zero)))
+                msgs[(t.src, t.dst)] = payload
+                modes[(t.src, t.dst)] = t.mode
+            delivered = sim.exchange(msgs)
+            for pair, payload in delivered.items():
+                dst = pair[1]
+                store = modes[pair] == "store"
+                for ds, c, v in payload:
+                    if c != 1:
+                        v = field.mul(np.uint64(c), v)
+                    if store:
+                        buf[dst][ds] = v
                     else:
-                        buf[k] = {s: out[k, i] for i, s in enumerate(step.out_slots)}
-            else:  # pragma: no cover
-                raise TypeError(f"unknown IR step {type(step).__name__}")
+                        buf[dst][ds] = field.add(buf[dst].get(ds, zero), v)
+        elif isinstance(step, LocalOp):
+            if step.coeffs is None:
+                raise ValueError(
+                    "structure-only IR (LocalOp.coeffs=None) cannot be "
+                    "interpreted — recompile with the generator matrix"
+                )
+            n_in = len(step.in_slots)
+            cols = np.zeros((K, n_in), dtype=np.uint64)
+            for j, s in enumerate(step.in_slots):
+                for k in range(K):
+                    cols[k, j] = buf[k].get(s, zero)
+            out = np.zeros((K, len(step.out_slots)), dtype=np.uint64)
+            for j in range(n_in):
+                out = field.add(
+                    out, field.mul(step.coeffs[:, :, j], cols[:, j][:, None])
+                )
+            for k in range(K):
+                if step.update:
+                    for i, s in enumerate(step.out_slots):
+                        buf[k][s] = out[k, i]
+                else:
+                    buf[k] = {s: out[k, i] for i, s in enumerate(step.out_slots)}
+        else:  # pragma: no cover
+            raise TypeError(f"unknown IR step {type(step).__name__}")
     result = np.array(
         [buf[place[k]].get(ir.out_slot, zero) for k in range(K)], dtype=np.uint64
     )

@@ -23,7 +23,7 @@ unchanged by the IR refactor and asserted at dispatch time
 the jaxpr tests pin).
 
 :func:`allgather_encode_jit` is the deliberate baseline that DOES
-all-gather, kept for benchmarks and as the cost-model foil.
+all-gather, kept as the cost-model foil.
 
 All device arithmetic is the uint32-only tier of core/field.py (Shoup
 multiplies by compile-time coefficient duals), so the same bodies lower for
@@ -84,9 +84,6 @@ def _bcast(coef, npay: int):
     return coef.reshape(coef.shape + (1,) * npay)
 
 
-KERNEL_MODES = ("jnp", "fused", "pallas")
-
-
 def _mesh_platform(mesh) -> str:
     """Platform of the devices the program is compiled for — the mesh's,
     not the process default backend (a TPU mesh built from a CPU-default
@@ -94,38 +91,23 @@ def _mesh_platform(mesh) -> str:
     return mesh.devices.flat[0].platform
 
 
-def _resolve_kernels(kernels: str | None, platform: str) -> str:
-    """LocalOp lowering mode: ``None`` auto-selects the Pallas kernels on a
-    TPU mesh and the batched-jnp fused lowering elsewhere; ``"jnp"`` is the
-    legacy per-coefficient loop kept as the flagged fallback."""
-    if kernels is None:
-        return "pallas" if platform == "tpu" else "fused"
-    if kernels not in KERNEL_MODES:
-        raise ValueError(f"kernels must be one of {KERNEL_MODES} or None, got {kernels!r}")
-    return kernels
+def _lowering(platform: str) -> str:
+    """LocalOp lowering for a mesh platform: ``"pallas"`` (the
+    ``gf_matmul``/``butterfly_mac`` kernels) on TPU, ``"fused"`` (one
+    batched Shoup contraction) elsewhere. The tests replace this function to
+    run the Pallas kernels in interpret mode on a CPU mesh."""
+    return "pallas" if platform == "tpu" else "fused"
 
 
-def _lower_local(step: LocalOp, bake, kernels: str) -> dict:
+def _lower_local(step: LocalOp, bake) -> dict:
     """Strength-reduce one LocalOp for the executor. Rows whose coefficients
     are uniform across devices split into three classes: all-zero rows write
     zeros, {0,1}-rows become pure madd chains (the pipeline pass's shadow
     copies and combines), and the remaining *general* rows are stacked into
     ONE batched contraction — a single Shoup-multiplied jnp expression in
-    ``fused`` mode, or one ``gf_matmul``/``butterfly_mac`` kernel call in
-    ``pallas`` mode. ``jnp`` keeps the legacy dense per-(i,j) loop."""
+    the ``fused`` lowering, or one ``gf_matmul``/``butterfly_mac`` kernel
+    call in the ``pallas`` one."""
     c = np.asarray(step.coeffs)
-    spec = {
-        "update": step.update,
-        "overlap": step.overlap,
-        "zero": (),
-        "adds": (),
-        "gen": tuple(range(len(step.out_slots))),
-        "coef_idx": None,
-        "dense": kernels == "jnp",
-    }
-    if spec["dense"]:
-        spec["coef_idx"] = bake(c)
-        return spec
     ones = np.all(c == 1, axis=0)
     zeros = np.all(c == 0, axis=0)
     uniform01 = ones | zeros
@@ -137,12 +119,13 @@ def _lower_local(step: LocalOp, bake, kernels: str) -> dict:
             add_rows.append((i, tuple(int(j) for j in np.nonzero(ones[i])[0])))
         else:
             gen_rows.append(i)
-    spec["zero"] = tuple(zero_rows)
-    spec["adds"] = tuple(add_rows)
-    spec["gen"] = tuple(gen_rows)
-    if gen_rows:
-        spec["coef_idx"] = bake(c[:, gen_rows, :])
-    return spec
+    return {
+        "update": step.update,
+        "zero": tuple(zero_rows),
+        "adds": tuple(add_rows),
+        "gen": tuple(gen_rows),
+        "coef_idx": bake(c[:, gen_rows, :]) if gen_rows else None,
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -156,10 +139,6 @@ def ir_encode_jit(
     ir: ScheduleIR,
     *,
     q: int = M31,
-    tracer=None,
-    topo=None,
-    metrics=None,
-    kernels: str | None = None,
     name: str = "ir_encode",
 ):
     """Jitted mesh executor of any :class:`ScheduleIR`: device ``k`` (the
@@ -178,32 +157,10 @@ def ir_encode_jit(
     ``placement`` (e.g. after ``topo.passes.remap_digits``) the caller
     permutes host-side: device ``placement[k]`` holds logical packet k.
 
-    ``tracer`` (a :class:`repro.obs.trace.Tracer`) opts into per-round
-    telemetry: instead of ONE fused jit over all rounds, each CommRound
-    (and each LocalOp) becomes its own jitted dispatch bracketed by
-    ``block_until_ready`` timestamps, producing exactly one span per
-    CommRound carrying its metadata — round index, transfer count, slots on
-    the wire, the α-β model's predicted µs on ``topo`` (default: the
-    paper's flat network), and the busiest-link calibration features
-    (level/msgs/elems) that ``repro.obs.feed`` refits α/β from. Measured
-    round times also land in the ``metrics`` registry (default: the
-    process-local ``repro.obs.metrics`` one) as ``encode.rounds``,
-    ``encode.ppermutes``, ``encode.bytes_on_wire`` and
-    ``encode.round_us{level=}``. With ``tracer=None`` (the default) the
-    fused path — and its jaxpr, ppermute budget, and HLO discipline — is
-    exactly as before; tracing changes dispatch granularity, never the
-    computed function. An ``overlap=True`` LocalOp (emitted by
-    ``topo.passes.pipeline_rounds``) is merged into the FOLLOWING comm
-    round's dispatch, so its contraction is issued concurrently with the
-    ppermute — the traced ``round[r]`` span carries ``overlap`` attrs.
-
-    ``kernels`` selects the LocalOp lowering: ``"pallas"`` routes general
-    rows through ``gf_matmul``/``butterfly_mac`` (compiled on a TPU mesh,
-    interpreted on any other), ``"fused"`` uses ONE batched Shoup
-    contraction per op, ``"jnp"`` keeps the legacy per-coefficient loop,
-    and ``None`` picks ``"pallas"`` when the mesh's devices are TPUs and
-    ``"fused"`` otherwise. All three are bit-exact (differential suite:
-    tests/test_fused_encode.py).
+    General LocalOp rows run through the ``gf_matmul``/``butterfly_mac``
+    Pallas kernels on a TPU mesh and through one batched Shoup contraction
+    (``"fused"``) on any other platform; both are bit-exact against
+    ``encode_oracle`` (tests/test_fused_encode.py).
 
     The fused program is named ``jit_<name>`` (``jit_ir_encode`` by
     default) in HLO and in a device profile, and each CommRound ``r`` and
@@ -212,7 +169,7 @@ def ir_encode_jit(
     """
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
     platform = _mesh_platform(mesh)
-    kernels = _resolve_kernels(kernels, platform)
+    lowering = _lowering(platform)
     pallas_interp = platform != "tpu"
     K = 1
     for ax in axes:
@@ -229,7 +186,7 @@ def ir_encode_jit(
         return len(consts) - 2
 
     # ("comm", [(pairs, src_slots, dst_slots, mode, coef_idx)], round_no)
-    # | ("local", out_slots, in_slots, coef_idx)
+    # | ("local", out_slots, in_slots, spec)
     ops = []
     round_no = -1
     for step in ir.steps:
@@ -267,8 +224,7 @@ def ir_encode_jit(
                     "recompile with the generator matrix"
                 )
             ops.append(
-                ("local", step.out_slots, step.in_slots,
-                 _lower_local(step, bake, kernels))
+                ("local", step.out_slots, step.in_slots, _lower_local(step, bake))
             )
         else:  # pragma: no cover
             raise TypeError(f"unknown IR step {type(step).__name__}")
@@ -302,20 +258,6 @@ def ir_encode_jit(
         _, out_slots, in_slots, spec = op
         xs = [buf.get(s, zero) for s in in_slots]  # all reads pre-op
         new = dict(buf) if spec["update"] else {}
-        if spec["dense"]:  # legacy "jnp" loop — the flagged fallback path
-            c, csh = cs[spec["coef_idx"]], cs[spec["coef_idx"] + 1]
-            for i, os_ in enumerate(out_slots):
-                acc = None
-                for j in range(len(in_slots)):
-                    term = shoup_mul(
-                        xs[j],
-                        _bcast(c[:, i, j], npay),
-                        _bcast(csh[:, i, j], npay),
-                        q,
-                    )
-                    acc = term if acc is None else madd(acc, term, q)
-                new[os_] = acc
-            return new
         for i in spec["zero"]:
             new[out_slots[i]] = zero
         for i, js in spec["adds"]:
@@ -326,7 +268,7 @@ def ir_encode_jit(
         if spec["gen"]:
             c, csh = cs[spec["coef_idx"]], cs[spec["coef_idx"] + 1]
             stacked = jnp.stack(xs, axis=1)  # (1, n_in, *pay)
-            if kernels == "pallas":
+            if lowering == "pallas":
                 from repro.kernels.butterfly.ops import butterfly_mac
                 from repro.kernels.gf_matmul.ops import gf_matmul
 
@@ -358,202 +300,28 @@ def ir_encode_jit(
         return new
 
     cs_dev = [jnp.asarray(a) for a in consts]
-
-    if tracer is None:
-        scopes, n_local = [], 0
-        for op in ops:
-            if op[0] == "comm":
-                scopes.append(f"round{op[2]}")
-            else:
-                scopes.append(f"local{n_local}")
-                n_local += 1
-
-        def body(x, cs):
-            buf = {INPUT_SLOT: x}
-            for scope, op in zip(scopes, ops):
-                with jax.named_scope(scope):
-                    buf = apply_op(op, buf, cs)
-            return buf[ir.out_slot]
-
-        mapped = _smap(
-            body, mesh, in_specs=(P(axes), P(axes)), out_specs=P(axes)
-        )
-
-        def encode(x):
-            return mapped(x, cs_dev)
-
-        encode.__name__ = encode.__qualname__ = name
-        return jax.jit(encode)
-    return _traced_runner(
-        mesh, axes, ir, ops, apply_op, cs_dev, tracer, topo, metrics
-    )
-
-
-def _traced_runner(mesh, axes, ir, ops, apply_op, cs_dev, tracer, topo, metrics):
-    """The opt-in per-round dispatch path of :func:`ir_encode_jit`: one
-    jitted shard_map per IR step, each bracketed by ``block_until_ready``
-    timestamps inside a tracer span. Slot liveness is tracked statically so
-    every step's buffer is a fixed tuple of (K, *payload) arrays; semantics
-    match the fused body exactly (missing slots read as 0 in both paths)."""
-    from repro.core.ir import ir_permute_count as _pc
-    from repro.obs.metrics import get_registry
-    from repro.topo.calibrate import round_features
-    from repro.topo.model import FullyConnected, schedule_time
-
-    if topo is None:
-        topo = FullyConnected(ir.K)
-    reg = metrics if metrics is not None else get_registry()
-
-    # An overlap-tagged LocalOp (pipeline_rounds' P_r) merges into the NEXT
-    # comm round's dispatch: one jitted step issues the contraction and the
-    # ppermute together, so XLA can run them concurrently — the traced
-    # round[r] span then covers (and shows) the overlap.
-    grouped = []
-    i = 0
-    while i < len(ops):
-        op = ops[i]
-        if (
-            op[0] == "local"
-            and op[3]["overlap"]
-            and i + 1 < len(ops)
-            and ops[i + 1][0] == "comm"
-        ):
-            grouped.append((op, ops[i + 1]))
-            i += 2
+    scopes, n_local = [], 0
+    for op in ops:
+        if op[0] == "comm":
+            scopes.append(f"round{op[2]}")
         else:
-            grouped.append((op,))
-            i += 1
+            scopes.append(f"local{n_local}")
+            n_local += 1
 
-    # static liveness: which slots hold data before each dispatch group
-    specs = []  # (kind, in_slots, out_slots, group)
-    live: tuple = (INPUT_SLOT,)
-    for grp in grouped:
-        cur = set(live)
-        for op in grp:
-            if op[0] == "comm":
-                cur |= {ds for g in op[1] for ds in g[2]}
-            elif op[3]["update"]:
-                cur |= set(op[1])
-            else:
-                cur = set(op[1])
-        outs = tuple(sorted(cur))
-        kind = "comm" if any(op[0] == "comm" for op in grp) else "local"
-        specs.append((kind, live, outs, grp))
-        live = outs
-
-    def make_step(grp, ins, outs):
-        def step(bufs, cs):
-            buf = dict(zip(ins, bufs))
-            for op in grp:
+    def body(x, cs):
+        buf = {INPUT_SLOT: x}
+        for scope, op in zip(scopes, ops):
+            with jax.named_scope(scope):
                 buf = apply_op(op, buf, cs)
-            zero = jnp.zeros_like(bufs[0])
-            return tuple(buf.get(s, zero) for s in outs)
+        return buf[ir.out_slot]
 
-        return jax.jit(
-            _smap(step, mesh, in_specs=(P(axes), P(axes)), out_specs=P(axes))
-        )
+    mapped = _smap(body, mesh, in_specs=(P(axes), P(axes)), out_specs=P(axes))
 
-    step_fns = [make_step(grp, ins, outs) for _, ins, outs, grp in specs]
+    def encode(x):
+        return mapped(x, cs_dev)
 
-    # per-comm-group metadata: the round's message map and its derived stats
-    comm_meta = {}
-    for idx, (kind, _, _, grp) in enumerate(specs):
-        if kind != "comm":
-            continue
-        op = next(o for o in grp if o[0] == "comm")
-        msgs: dict = {}
-        wire_slots = 0
-        n_transfers = 0
-        max_slots = 0
-        for pairs, src_slots, _, _, _ in op[1]:
-            n_transfers += len(pairs)
-            wire_slots += len(pairs) * len(src_slots)
-            max_slots = max(max_slots, len(src_slots))
-            for s, d in pairs:
-                msgs[(s, d)] = msgs.get((s, d), 0) + len(src_slots)
-        feats = round_features([msgs], topo)
-        overlap_op = next((o for o in grp if o[0] == "local"), None)
-        comm_meta[idx] = {
-            "round": op[2],
-            "msgs_map": msgs,
-            "transfers": n_transfers,
-            "ppermutes": len(op[1]),
-            "slots": max_slots,
-            "wire_slots": wire_slots,
-            "feature": feats[0] if feats else None,
-            "overlap_out_slots": len(overlap_op[1]) if overlap_op else 0,
-        }
-    n_rounds = len(comm_meta)
-    total_ppermutes = _pc(ir)
-
-    def run(x):
-        x = jnp.asarray(x)
-        payload_elems = 1
-        for d in x.shape[1:]:
-            payload_elems *= int(d)
-        with tracer.span(
-            "ir_encode",
-            algorithm=ir.algorithm,
-            K=ir.K,
-            p=ir.p,
-            rounds=n_rounds,
-            ppermutes=total_ppermutes,
-            payload_elems=payload_elems,
-        ):
-            bufs = (x,)
-            jax.block_until_ready(bufs)
-            for idx, (kind, ins, outs, grp) in enumerate(specs):
-                fn = step_fns[idx]
-                if kind == "comm":
-                    meta = comm_meta[idx]
-                    pred_us = (
-                        schedule_time(
-                            topo, [meta["msgs_map"]], payload_elems
-                        ).total
-                        * 1e6
-                    )
-                    feat = meta["feature"]
-                    attrs = {
-                        "algorithm": ir.algorithm,
-                        "comm_round": meta["round"],
-                        "transfers": meta["transfers"],
-                        "ppermutes": meta["ppermutes"],
-                        "slots": meta["slots"],
-                        "wire_slots": meta["wire_slots"],
-                        "payload_elems": payload_elems,
-                        "predicted_us": pred_us,
-                    }
-                    if meta["overlap_out_slots"]:
-                        attrs["overlap"] = True
-                        attrs["overlap_out_slots"] = meta["overlap_out_slots"]
-                    if feat is not None:
-                        attrs.update(
-                            level=feat["level"],
-                            msgs=feat["msgs"],
-                            elems=feat["elems"],
-                        )
-                    with tracer.span(f"round[{meta['round']}]", **attrs) as sp:
-                        bufs = fn(bufs, cs_dev)
-                        jax.block_until_ready(bufs)
-                    reg.counter("encode.rounds").inc()
-                    reg.counter("encode.ppermutes").inc(meta["ppermutes"])
-                    reg.counter("encode.bytes_on_wire").inc(
-                        meta["wire_slots"] * payload_elems * 4
-                    )
-                    if feat is not None:
-                        reg.histogram(
-                            "encode.round_us", level=feat["level"]
-                        ).observe(sp.dur_us)
-                    else:
-                        reg.histogram("encode.round_us").observe(sp.dur_us)
-                else:
-                    with tracer.span(f"local[{idx}]", kind="local"):
-                        bufs = fn(bufs, cs_dev)
-                        jax.block_until_ready(bufs)
-            out_by_slot = dict(zip(outs, bufs)) if specs else {INPUT_SLOT: x}
-            return out_by_slot.get(ir.out_slot, jnp.zeros_like(x))
-
-    return run
+    encode.__name__ = encode.__qualname__ = name
+    return jax.jit(encode)
 
 
 # ---------------------------------------------------------------------------
@@ -584,18 +352,16 @@ def expected_permute_count(plan: PrepareShootPlan) -> int:
     return count
 
 
-def _apply_pipeline(ir: ScheduleIR, pipeline: str, payload_elems: int = 1 << 16):
+def _apply_pipeline(ir: ScheduleIR, pipeline: str):
     """Apply a named ``topo.passes`` pipeline at dispatch time (e.g.
     ``pipeline="pipeline"`` for the software-pipelined rounds picked by the
-    autotuner / a launch profile). Priced against a flat fabric at a
-    representative payload; comm rounds are never touched, so the entry
-    point's ppermute budget check still binds the rewritten IR."""
+    autotuner / a launch profile). Comm rounds are never touched, so the
+    entry point's ppermute budget check still binds the rewritten IR."""
     if not pipeline:
         return ir
-    from repro.topo.model import FullyConnected
-    from repro.topo.passes import PIPELINES
+    from repro.topo.passes import apply_pipeline
 
-    return PIPELINES[pipeline].apply(ir, FullyConnected(ir.K), payload_elems)
+    return apply_pipeline(ir, pipeline)
 
 
 def _check_budget(ir: ScheduleIR, budget: int):
@@ -613,7 +379,6 @@ def ps_encode_jit(
     *,
     p: int = 1,
     q: int = M31,
-    kernels: str | None = None,
     pipeline: str = "",
 ):
     """Jitted mesh executor of the universal encode: ``out = x @ A`` over
@@ -632,13 +397,13 @@ def ps_encode_jit(
     plan = plan_prepare_shoot(K, p)
     ir = _apply_pipeline(plan.to_ir(A, q=q), pipeline)
     _check_budget(ir, expected_permute_count(plan))
-    return ir_encode_jit(mesh, axis, ir, q=q, kernels=kernels, name="ps_encode"), plan
+    return ir_encode_jit(mesh, axis, ir, q=q, name="ps_encode"), plan
 
 
 def allgather_encode_jit(mesh, axis: str, A: np.ndarray, *, q: int = M31):
     """Baseline mesh encode: all-gather every packet, then each device
     contracts locally with its own column of A — C1 = O(log K) but
-    C2 = Θ(K/p). Kept as the benchmark/cost-model foil for ps_encode_jit
+    C2 = Θ(K/p). Kept as the cost-model foil for ps_encode_jit
     (deliberately NOT routed through ir_encode_jit: its point is the
     all-gather the IR path never emits)."""
     K = int(mesh.shape[axis])
@@ -696,7 +461,6 @@ def hierarchical_encode_jit(
     *,
     p: int = 1,
     q: int = M31,
-    kernels: str | None = None,
     pipeline: str = "",
 ):
     """Jitted two-level mesh executor of the universal encode: ``out = x @ A``
@@ -733,10 +497,7 @@ def hierarchical_encode_jit(
     plan = plan_hierarchical(K, p, k_intra=I)
     ir = _apply_pipeline(plan.to_ir(A, q=q), pipeline)
     _check_budget(ir, expected_hier_permute_count(plan))
-    return (
-        ir_encode_jit(mesh, (inter_axis, intra_axis), ir, q=q, kernels=kernels),
-        plan,
-    )
+    return ir_encode_jit(mesh, (inter_axis, intra_axis), ir, q=q), plan
 
 
 # ---------------------------------------------------------------------------
@@ -766,7 +527,6 @@ def multilevel_encode_jit(
     *,
     p: int = 1,
     q: int = M31,
-    kernels: str | None = None,
     pipeline: str = "",
 ):
     """Jitted N-level mesh executor of the universal encode: ``out = x @ A``
@@ -805,7 +565,7 @@ def multilevel_encode_jit(
     plan = plan_multilevel(K, p, levels)
     ir = _apply_pipeline(plan.to_ir(A, q=q), pipeline)
     _check_budget(ir, expected_multilevel_permute_count(plan))
-    return ir_encode_jit(mesh, axes, ir, q=q, kernels=kernels), plan
+    return ir_encode_jit(mesh, axes, ir, q=q), plan
 
 
 # ---------------------------------------------------------------------------
@@ -820,7 +580,6 @@ def butterfly_jit(
     p: int = 1,
     q: int = NTT,
     inverse: bool = False,
-    kernels: str | None = None,
     pipeline: str = "",
 ):
     """Jitted mesh butterfly: forward computes ``x @ butterfly_target_matrix``
@@ -835,4 +594,4 @@ def butterfly_jit(
     plan = plan_butterfly(K, p, q)
     ir = _apply_pipeline(plan.to_ir(inverse=inverse), pipeline)
     _check_budget(ir, plan.H * p)
-    return ir_encode_jit(mesh, axis, ir, q=q, kernels=kernels), plan
+    return ir_encode_jit(mesh, axis, ir, q=q), plan

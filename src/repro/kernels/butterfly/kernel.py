@@ -5,8 +5,7 @@ column n:   out[b, n] = Σ_ρ tw[b, ρ] · parts[ρ, b, n]   (mod q).
 
 Fusing the radix Shoup-multiplies and modular adds into one kernel avoids
 ``radix - 1`` HBM round-trips of the (B, P) intermediate that the naive
-composition materializes (the memory-roofline win measured in
-benchmarks/bench_kernels.py). All arithmetic is uint32-only (Shoup with
+composition materializes. All arithmetic is uint32-only (Shoup with
 precomputed duals; no 64-bit values), so the body lowers for TPU VPU lanes.
 
 Tiling: grid (B/bb, P/bp); twiddle blocks are (bb, radix) and broadcast over

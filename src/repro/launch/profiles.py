@@ -152,19 +152,13 @@ class EncodeProfile:
     ``dist.collectives.ir_encode_jit`` executes (structure-only here: the
     executors recompile with the generator matrix at dispatch and re-apply
     the named pipeline, e.g. ``pipeline="pipeline"`` for the
-    comm/compute-overlap rewrite). ``kernels`` is the LocalOp lowering the
-    executors should use (None = auto: Pallas kernels on TPU, the batched
-    fused-jnp contraction elsewhere; "jnp" = the legacy unfused loop kept
-    behind the flag). ``fitted_costs`` records the calibrated per-level
-    α/β the pricing used (None = v5e defaults)."""
+    comm/compute-overlap rewrite)."""
 
     topology: object  # repro.topo Topology the choice was priced on
     algorithm: str
     plan: object
     tune: object  # full repro.topo.TuneResult (candidate table)
     pipeline: str = ""  # winning PassPipeline name ("" = un-rewritten)
-    fitted_costs: tuple | None = None  # calibrated LinkCosts used for pricing
-    kernels: str | None = None  # ir_encode_jit LocalOp lowering (None = auto)
 
     @property
     def levels(self) -> tuple[int, ...]:
@@ -185,8 +179,6 @@ def resolve_profile(
     q: int | None = None,
     measured: dict[str, float] | None = None,
     generator: str | None = None,
-    calibration: str | bool | None = None,
-    kernels: str | None = None,
 ) -> EncodeProfile:
     """Pick the coded-checkpoint DP-axis encode algorithm from the mesh
     topology via the autotuner (ROADMAP: "wire the autotuner into launch/").
@@ -196,37 +188,16 @@ def resolve_profile(
     recursive multi-level schedule. Pass ``mesh`` + ``axes`` (outermost →
     innermost, e.g. ``("pod", "slice", "chip")``) to derive the hierarchy
     from a live mesh instead. ``measured`` feeds wall-clock calibration
-    (e.g. ``results/BENCH_topology.json``'s ``measured_s``) through
-    ``autotune(..., measured=...)``.
+    through ``autotune(..., measured=...)``.
 
     ``generator`` is the autotuner taxonomy kind; when omitted it defaults
     from the checkpoint layer's actual generator matrix kind (Cauchy →
     "general") via :func:`generator_kind_for` — callers with structured
     generators pass ``generator=generator_kind_for("vandermonde")`` etc. to
-    unlock the structured candidate families.
-
-    ``calibration`` selects fitted α/β pricing: ``None`` (default) loads
-    ``results/BENCH_topology.json`` when present, a path loads that file,
-    ``False`` disables calibration. A path ending in ``.jsonl`` or
-    ``.trace.json`` is treated as a span trace emitted by
-    ``dist.collectives.ir_encode_jit(tracer=...)`` and re-fit on the fly
-    via ``obs.feed.fitted_costs_from_trace`` — live telemetry straight
-    into pricing, no intermediate results file. When fitted per-level
-    costs exist and
-    the priced topology is a Hierarchy, its level costs are replaced by the
-    fit (level counts matching exactly, otherwise the fitted innermost/
-    outermost endpoints re-interpolated through
-    ``topo.model.default_level_costs``) so candidate prices — and the chosen
-    (algorithm, pipeline) — reflect measured hardware.
-
-    ``kernels`` is recorded verbatim on the profile for dispatch-time use
-    (``dist.collectives`` LocalOp lowering mode: None = auto-select by
-    backend, "pallas"/"fused"/"jnp" to force)."""
+    unlock the structured candidate families."""
     from repro.core.field import M31
     from repro.launch.mesh import production_topology, topology_for_mesh
     from repro.topo import autotune
-    from repro.topo.calibrate import load_fitted_costs
-    from repro.topo.model import Hierarchy, default_level_costs
 
     if mesh is not None:
         if axes is None:
@@ -234,36 +205,6 @@ def resolve_profile(
         topo = topology_for_mesh(mesh, axes)
     else:
         topo = production_topology(multi_pod=multi_pod)
-    fitted = None
-    if calibration is not False:
-        if isinstance(calibration, str) and calibration.endswith(
-            (".jsonl", ".trace.json")
-        ):
-            from repro.obs.feed import fitted_costs_from_trace
-
-            try:
-                fitted = tuple(fitted_costs_from_trace(calibration))
-            except (OSError, ValueError):  # unreadable/unfittable trace
-                fitted = None
-        else:
-            fitted = load_fitted_costs(
-                calibration if isinstance(calibration, str) else None
-            )
-    if fitted is not None and isinstance(topo, Hierarchy):
-        from dataclasses import replace as _replace
-
-        if len(fitted) == len(topo.levels):
-            topo = _replace(topo, costs=fitted)
-        else:
-            topo = _replace(
-                topo,
-                costs=default_level_costs(
-                    len(topo.levels), lo=fitted[0], hi=fitted[-1]
-                ),
-            )
-        fitted = topo.costs
-    else:
-        fitted = None
     result = autotune(
         topo.n,
         p,
@@ -281,6 +222,4 @@ def resolve_profile(
         plan=result.chosen.plan,
         tune=result,
         pipeline=result.chosen.pipeline,
-        fitted_costs=fitted,
-        kernels=kernels,
     )

@@ -1,20 +1,19 @@
-# Runtime telemetry for the encode pipeline (ROADMAP: live-fed calibration).
+# Runtime telemetry: spans and metrics for the serving loop and the coded
+# layer.
 #
 # - trace.py    Tracer/Span: nestable, attributed wall-clock spans, each
 #               also a jax.profiler.TraceAnnotation on the profiler's
-#               clock; the instrumented layers (ir_encode_jit(tracer=...),
-#               the interpret oracle, the serving engines and the coded
-#               guard, benchmarks/run.py --trace) stamp their counts and
-#               per-CommRound metadata onto them
+#               clock; the serving engines and the coded guard open them
+#               around their phases and stamp their counts on them
 # - export.py   Chrome-trace-event JSON (Perfetto-loadable) + JSONL span
 #               sinks under results/traces/, and the reader for both
 # - metrics.py  process-local counters/gauges/histograms registry with
-#               deterministic JSON snapshots (encode.rounds,
-#               encode.round_us{level=}, serve.step_us, ...)
-# - feed.py     the live calibration loop: traced round spans → per-level
-#               α/β refit → persisted where topo.calibrate.load_fitted_costs
-#               (and hence launch.profiles.resolve_profile) reads them,
-#               plus the predicted-vs-measured drift rows perf_report renders
+#               deterministic JSON snapshots (serve.step_us,
+#               serve.ttft_ms, serve.snapshots, ...)
+#
+# The encode's per-round device times come from a device profile of its one
+# fused program: ir_encode_jit scopes each CommRound's ops round<r> and each
+# LocalOp's local<i>.
 
 from .export import (  # noqa: F401
     DEFAULT_TRACE_DIR,
@@ -23,15 +22,6 @@ from .export import (  # noqa: F401
     spans_to_chrome,
     write_chrome_trace,
     write_spans_jsonl,
-)
-from .feed import (  # noqa: F401
-    comm_round_spans,
-    drift_rows,
-    feed_calibration,
-    fitted_costs_from_trace,
-    persist_fitted_costs,
-    refit_from_spans,
-    round_measurements,
 )
 from .metrics import (  # noqa: F401
     REGISTRY,

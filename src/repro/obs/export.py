@@ -7,12 +7,9 @@ Two formats, one source of truth (:class:`~repro.obs.trace.Span`):
   per span, sorted by start time. Load the file in Perfetto / ``chrome://
   tracing``; nesting renders from event containment on one track. Span
   attributes travel in ``args`` (JSON-safe stringification for anything
-  exotic), so the per-round ``CommRound`` metadata — round index, transfer
-  count, predicted µs — is inspectable in the UI and machine-checkable by
-  ``tools/check_trace.py``.
+  exotic), so span metadata is inspectable in the UI.
 * :func:`write_spans_jsonl` — one span dict per line under
-  ``results/traces/`` by default: the machine-first sink
-  ``repro.obs.feed`` and ``launch.perf_report.render_drift`` consume.
+  ``results/traces/`` by default: the machine-first sink.
 
 :func:`read_spans` loads either format back into plain span dicts (the
 shape ``Span.to_dict`` produces), so every downstream consumer is

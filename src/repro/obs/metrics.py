@@ -2,16 +2,10 @@
 
 One :class:`MetricsRegistry` per process (module-level :data:`REGISTRY`,
 reachable via :func:`get_registry`) holds named instruments, optionally
-labelled — ``registry.histogram("encode.round_us", level=1)`` materializes
-the series ``encode.round_us{level=1}``. Instrument names in use across the
+labelled — ``registry.histogram("serve.step_us", slot=1)`` materializes
+the series ``serve.step_us{slot=1}``. Instrument names in use across the
 repo:
 
-* ``encode.rounds`` / ``encode.ppermutes`` / ``encode.bytes_on_wire`` —
-  counters bumped per traced :class:`~repro.core.ir.CommRound` by
-  ``dist.collectives.ir_encode_jit(tracer=...)``;
-* ``encode.round_us{level=j}`` — histogram of measured per-round wall µs,
-  labelled by the round's topology level (the rows ``repro.obs.feed``
-  refits α/β from);
 * ``serve.step_us`` / ``serve.tokens_per_s`` / ``serve.eos_syncs_saved`` —
   the fixed-batch serving engine's decode-step latency histogram, its
   generated-tokens-only throughput gauge (shared with the continuous
@@ -28,9 +22,7 @@ repo:
   coded straggler-tolerant serving (``serve.coded.CodedServeGuard``):
   LCC snapshots of the decode-path state taken per chunk, hosts
   recovered from after injected/real faults, and the any-K-of-N
-  Lagrange reconstruction latency histogram;
-* ``bench.*_us`` — benchmark sample histograms routed through
-  ``benchmarks.common.time_fn(metric=...)``.
+  Lagrange reconstruction latency histogram.
 
 Snapshots are deterministic: keys sorted, histogram statistics derived
 from the full sample list (count/sum/min/max/mean/p50/p90/p99), so two

@@ -1,4 +1,4 @@
-"""Nestable span tracing for the encode pipeline and the serving loop.
+"""Nestable span tracing for the serving loop and the coded layer.
 
 A :class:`Tracer` records :class:`Span` records — named, attributed,
 wall-clocked intervals on one monotonic timeline (``time.perf_counter``
@@ -14,15 +14,11 @@ With no profile running an annotation costs next to nothing. JAX is
 imported on the first span, not with this module.
 
 The tracer is deliberately dumb — no sampling, no threads, no flushing
-policy. Instrumented layers (``dist.collectives.ir_encode_jit(tracer=...)``,
-``core.simulator.interpret(tracer=...)``, ``serve.engine.Engine``,
-``serve.engine.ContinuousEngine``, ``serve.coded.CodedServeGuard``,
-``benchmarks/run.py --trace``) open spans around their rounds/steps and
-attach the :class:`~repro.core.ir.CommRound` metadata (round index,
-transfer count, slots on the wire, predicted µs from the α-β model) as
-span attributes; ``repro.obs.feed`` then turns those attributed spans back
-into calibration measurements. Code that takes an optional tracer opens
-its spans through :func:`optional_span`.
+policy. Instrumented layers (``serve.engine.Engine``,
+``serve.engine.ContinuousEngine``, ``serve.coded.CodedServeGuard``) open
+spans around their steps and phases and attach their counts as span
+attributes. Code that takes an optional tracer opens its spans through
+:func:`optional_span`.
 """
 
 from __future__ import annotations

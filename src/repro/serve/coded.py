@@ -480,7 +480,6 @@ class CodedServeGuard:
         hosts: ProcessHostPool | None = None,
         mesh=None,
         axis: str | None = None,
-        kernels: str | None = None,
     ):
         if R < 1:
             raise ValueError("coded serving needs R ≥ 1 parity shards")
@@ -491,9 +490,7 @@ class CodedServeGuard:
         if mesh is not None:
             if axis is None:
                 raise ValueError("mesh= requires axis=")
-            self._encode = lcc_encode_collective(
-                mesh, axis, self.plan, kernels=kernels
-            )
+            self._encode = lcc_encode_collective(mesh, axis, self.plan)
         else:
             plan = self.plan
 

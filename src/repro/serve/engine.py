@@ -212,8 +212,7 @@ class Engine:
 @dataclass
 class ServeReport:
     """Outcome of one :meth:`ContinuousEngine.serve` run: per-request
-    results (arrival order) + the latency/throughput aggregates the
-    traffic harness commits to ``results/BENCH_serve.json``."""
+    results (arrival order) + the latency/throughput aggregates."""
 
     results: list[RequestResult]
     wall_s: float
@@ -236,7 +235,7 @@ class ServeReport:
         return int(self.coded["requests_recovered"]) if self.coded else 0
 
     def to_record(self) -> dict:
-        """JSON-ready engine row for BENCH_serve.json."""
+        """JSON-ready row of the aggregates."""
         rec = {
             "tokens_per_s": self.tokens_per_s,
             "ttft_ms": dict(self.ttft_ms),

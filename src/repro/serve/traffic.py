@@ -1,13 +1,13 @@
 """Synthetic serving traffic: seeded Poisson arrivals over a mixed
 prompt-length distribution.
 
-``poisson_trace`` is the workload generator behind ``benchmarks/
-bench_serve.py``: exponential interarrival gaps at ``rate_rps`` requests
+``poisson_trace`` is the workload generator of the serving example and
+tests: exponential interarrival gaps at ``rate_rps`` requests
 per second, each request drawing its prompt length from a weighted set of
 :class:`LengthBand`\\ s (short chat turns vs. long documents) and its
 token ids uniformly from the vocabulary. Everything is derived from one
 ``numpy`` Generator seed, so the fixed-batch baseline and the continuous
-engine can be measured on the *same* trace and two benchmark runs produce
+engine can be measured on the *same* trace and two runs produce
 identical workloads.
 """
 

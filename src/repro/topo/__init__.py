@@ -15,8 +15,7 @@
 #                    rewrites with applicability predicates (remap_digits,
 #                    split_contended, fuse_rounds, align_subgroups) and the
 #                    PassPipeline registry the autotuner enumerates
-# - calibrate.py     least-squares per-level α/β from measured sweeps +
-#                    load_fitted_costs (persisted calibration → LinkCosts)
+# - calibrate.py     least-squares per-level α/β from measured sweeps
 # - autotune.py      per-(K, p, payload, topology) selection by enumerating
 #                    and pricing (algorithm, pipeline) ScheduleIR candidates,
 #                    with a measured-override hook
@@ -25,11 +24,7 @@
 # per-algorithm *_encode_jit entry points dispatch through it.
 
 from .autotune import Candidate, TuneResult, autotune, candidates_for  # noqa: F401
-from .calibrate import (  # noqa: F401
-    fit_level_costs,
-    load_fitted_costs,
-    round_features,
-)
+from .calibrate import fit_level_costs, round_features  # noqa: F401
 from .hierarchical import (  # noqa: F401
     HierarchicalPlan,
     MultiLevelDFTPlan,

@@ -35,9 +35,9 @@ plan factorization is taken from the topology itself, so the schedule's
 phases align with the hardware's levels by construction.
 
 A ``measured`` override hook replaces predicted times with wall-clock
-numbers (e.g. from benchmarks/bench_topology.py) without changing the
-selection logic; ``topo.calibrate.fit_level_costs`` turns the same sweeps
-into fitted per-level α/β.
+numbers without changing the selection logic;
+``topo.calibrate.fit_level_costs`` turns the same sweeps into fitted
+per-level α/β.
 
 Paper-notation glossary: ``K`` processors, ``p`` ports, ``C1`` rounds,
 ``C2`` per-port elements (paper §I); ``I``/``G`` the two-level k_intra ×

@@ -9,40 +9,19 @@ level, so a measured wall time decomposes linearly:
                       elems_on_busiest_link · payload · β_level)
 
 :func:`round_features` extracts the per-round (level, msgs, elems) rows from
-any lowered schedule, and :func:`fit_level_costs` least-squares the stacked
-sweep (multiple algorithms × payload sizes, e.g. the ``calibration`` block
-``benchmarks/bench_topology.py`` writes into ``results/BENCH_topology.json``)
-into one :class:`~repro.topo.model.LinkCost` per level — ready to pass as
+any lowered schedule, and :func:`fit_level_costs` least-squares a stacked
+sweep (multiple algorithms × payload sizes) into one
+:class:`~repro.topo.model.LinkCost` per level — ready to pass as
 ``Hierarchy(levels, costs=fitted)`` or compare against
-``default_level_costs``. This is the ROADMAP's "fit per-level α/β from
-sweeps instead of the v5e constants" item.
-
-Two measurement sources feed the same fit: the offline aggregate sweep
-(whole-encode wall times × analytic :func:`round_features` rows, as the
-benchmark's ``calibration.samples``) and the live traced path —
-``dist.collectives.ir_encode_jit(tracer=...)`` stamps each round span with
-its (level, msgs, elems) feature, and ``repro.obs.feed`` turns those spans
-into per-round measurements, refits, and persists exactly where
-:func:`load_fitted_costs` reads.
+``default_level_costs``. No sweep measured on the chip feeds it yet: the
+topology's own constants price every schedule.
 """
 
 from __future__ import annotations
 
-import json
-import os
-
 import numpy as np
 
 from .model import Hierarchy, LinkCost, round_link_loads
-
-#: default location of the persisted calibration block (repo-root relative)
-DEFAULT_CALIBRATION_PATH = os.path.join(
-    os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    ),
-    "results",
-    "BENCH_topology.json",
-)
 
 
 def round_features(rounds, topo: Hierarchy) -> list[dict]:
@@ -102,48 +81,3 @@ def fit_level_costs(measurements, n_levels: int) -> tuple[LinkCost, ...]:
         LinkCost(alpha=float(theta[2 * j]), beta=float(theta[2 * j + 1]))
         for j in range(n_levels)
     )
-
-
-def load_fitted_costs(path: str | None = None) -> tuple[LinkCost, ...] | None:
-    """Load the fitted per-level α/β that ``benchmarks/bench_topology.py``
-    persists under ``calibration.fitted_level_costs`` in
-    ``results/BENCH_topology.json`` (or any file of the same shape).
-
-    Returns one :class:`~repro.topo.model.LinkCost` per level (innermost
-    first) — ready for ``Hierarchy(levels, costs=fitted)`` so the autotuner
-    and ``launch.profiles.resolve_profile`` price candidates with measured
-    constants instead of the v5e defaults. Returns ``None`` when the file or
-    its calibration block is absent (no benchmark has run yet); falls back
-    to re-fitting from the persisted raw ``samples`` when only those exist."""
-    path = path if path is not None else DEFAULT_CALIBRATION_PATH
-    if not os.path.exists(path):
-        return None
-    try:
-        with open(path) as fh:
-            record = json.load(fh)
-    except (OSError, json.JSONDecodeError):
-        return None
-    cal = record.get("calibration") or {}
-    rows = cal.get("fitted_level_costs")
-    if rows:
-        try:
-            by_level = {int(r["level"]): r for r in rows}
-            return tuple(
-                LinkCost(
-                    alpha=float(by_level[j]["alpha_s"]),
-                    beta=float(by_level[j]["beta_s_per_elem"]),
-                )
-                for j in range(len(by_level))
-            )
-        except (KeyError, TypeError, ValueError):
-            return None
-    samples = cal.get("samples")
-    if samples:
-        n_levels = 1 + max(
-            int(r["level"]) for m in samples for r in m.get("rounds", ())
-        )
-        try:
-            return fit_level_costs(samples, n_levels)
-        except (KeyError, ValueError):
-            return None
-    return None

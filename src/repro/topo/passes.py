@@ -6,9 +6,9 @@ rewrite layer into a real optimizer. A :class:`Pass` is a named
 ``(ScheduleIR, Topology) -> ScheduleIR`` rewrite with an applicability
 predicate; a :class:`PassPipeline` is a named composition of passes. The
 autotuner (``topo.autotune``) enumerates every applicable pipeline per
-compiled IR, prices the rewritten IR with the α-β estimator (fitted α/β when
-calibration exists — see ``topo.calibrate.load_fitted_costs``), and records
-the winning (algorithm, pipeline) pair.
+compiled IR, prices the rewritten IR with the α-β estimator on the
+topology's own link costs, and records the winning (algorithm, pipeline)
+pair.
 
 Every pass is **exact**: the rewritten IR computes the same encode function,
 proven against the oracle interpreter for every registered pipeline × every
@@ -74,6 +74,7 @@ from repro.core.ir import (
 
 from .model import (
     MAC_SECONDS,
+    FullyConnected,
     Hierarchy,
     Topology,
     Torus2D,
@@ -834,6 +835,13 @@ PIPELINES: dict[str, PassPipeline] = {
         ),
     ]
 }
+
+
+def apply_pipeline(ir: ScheduleIR, name: str, payload_elems: int = 1 << 16):
+    """Apply the registered pipeline ``name`` as the mesh executors do at
+    dispatch time: priced against a flat fabric of ``ir.K`` processors at a
+    representative payload."""
+    return PIPELINES[name].apply(ir, FullyConnected(ir.K), payload_elems)
 
 
 def pipelines_for(ir: ScheduleIR, topo: Topology) -> list[PassPipeline]:

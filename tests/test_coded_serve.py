@@ -11,15 +11,11 @@ Acceptance:
   process (``ProcessHostPool``) mid-decode while the guard's encode runs
   through the mesh collective (``ir_encode_jit``) — still bit-identical,
   ``serve.recoveries`` ≥ 1.
-* ``tools/check_trace.py --kind coded-serve`` gates fresh and committed
-  ``BENCH_coded_serve.json`` records (recoveries ≥ injected faults,
-  ordered recovery percentiles, token-identity flag).
 """
 
 import contextlib
 import functools
 import itertools
-import json
 import os
 import subprocess
 import sys
@@ -542,102 +538,3 @@ def test_decode_group_host_count_mismatch():
     with ProcessHostPool(4) as pool:  # needs 5
         with pytest.raises(ValueError, match="need N=5"):
             CodedDecodeGroup(plan, hosts=pool)
-
-
-# ---------------------------------------------------------------------------
-# validator: coded-serve record kind, fresh + committed
-# ---------------------------------------------------------------------------
-
-
-def _coded_serve_record(**edits):
-    cont = {
-        "tokens_per_s": 100.0, "ttft_ms": {"p50": 1.0, "p99": 2.0},
-        "e2e_ms": {"p50": 3.0, "p99": 4.0}, "n_requests": 4, "wall_s": 0.5,
-        "slot_occupancy": 0.8, "prefill_compiles": 2, "decode_steps": 40,
-    }
-    coded_blk = {
-        "K": 3, "R": 2, "n_hosts": 5, "injected_faults": 1, "recoveries": 1,
-        "requests_recovered": 2, "snapshots": 9,
-        "recovery_us": {"p50": 100.0, "p99": 200.0},
-    }
-    rec = {
-        "workload": {"n_requests": 4, "rate_rps": 50.0, "seed": 0},
-        "n_slots": 2,
-        "buckets": [8, 16],
-        "coded": {"K": 3, "R": 2, "n_hosts": 5},
-        "engines": {"uncoded": dict(cont), "coded": dict(cont)},
-        "fault_scenarios": [
-            {"kills": 1, "tokens_identical": True, "tokens_per_s": 90.0,
-             "coded": dict(coded_blk)},
-        ],
-    }
-    for dotted, v in edits.items():
-        cur = rec
-        parts = dotted.split(".")
-        for p in parts[:-1]:
-            cur = cur[int(p)] if p.isdigit() else cur[p]
-        cur[parts[-1]] = v
-    return rec
-
-
-def test_check_trace_coded_serve_kind():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import check_trace
-
-        assert check_trace.check_coded_serve(_coded_serve_record()) == []
-        # a fault went unrecovered
-        bad = _coded_serve_record(**{"fault_scenarios.0.coded.recoveries": 0})
-        assert check_trace.check_coded_serve(bad)
-        # recovery latency percentiles out of order
-        bad = _coded_serve_record(
-            **{"fault_scenarios.0.coded.recovery_us": {"p50": 9.0, "p99": 2.0}}
-        )
-        assert check_trace.check_coded_serve(bad)
-        # recoveries claimed but latency never measured
-        bad = _coded_serve_record(
-            **{"fault_scenarios.0.coded.recovery_us": {"p50": 0.0, "p99": 0.0}}
-        )
-        assert check_trace.check_coded_serve(bad)
-        # token identity must hold
-        bad = _coded_serve_record(**{"fault_scenarios.0.tokens_identical": False})
-        assert check_trace.check_coded_serve(bad)
-        # missing the recovery block entirely
-        bad = _coded_serve_record()
-        del bad["fault_scenarios"][0]["coded"]
-        assert check_trace.check_coded_serve(bad)
-    finally:
-        sys.path.pop(0)
-
-
-def test_check_trace_coded_serve_cli_fresh(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import check_trace
-
-        path = tmp_path / "BENCH_coded_serve.json"
-        path.write_text(json.dumps(_coded_serve_record()))
-        assert check_trace.main([str(path)]) == 0  # auto-detected
-        assert check_trace.main(["--kind", "coded-serve", str(path)]) == 0
-    finally:
-        sys.path.pop(0)
-
-
-def test_committed_bench_record_gates():
-    """The committed BENCH_coded_serve.json must pass the validator and
-    show ≥ 1 recovery with token identity (the PR's acceptance bar)."""
-    path = os.path.join(REPO, "results", "BENCH_coded_serve.json")
-    assert os.path.exists(path), "results/BENCH_coded_serve.json not committed"
-    with open(path) as fh:
-        rec = json.load(fh)
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import check_trace
-
-        assert check_trace.check_coded_serve(rec) == []
-    finally:
-        sys.path.pop(0)
-    assert any(
-        s["coded"]["recoveries"] >= 1 and s["tokens_identical"]
-        for s in rec["fault_scenarios"]
-    )

@@ -12,12 +12,12 @@ Three layers of evidence, cheapest first:
   with ``overlap=True`` shadow contractions) on the prologue-heavy families
   at 64k-element payloads, and prices strictly cheaper there;
 * **subprocess mesh differential** — on a forced-host 8-device mesh the
-  three executor lowerings (``kernels ∈ {jnp, fused, pallas}``, pallas in
-  interpret mode on CPU) × {no pipeline, "pipeline"} all produce the exact
-  oracle bytes, the pipelined executors keep the committed jaxpr ppermute
-  budgets, the compiled HLO stays collective-permute-only, and a traced
-  pipelined run emits overlap-annotated round spans that pass
-  ``tools/check_trace.py``.
+  two executor lowerings (``fused``, and ``pallas`` in interpret mode,
+  pinned through the ``dist.collectives._lowering`` seam) × {no pipeline,
+  "pipeline"} all produce the exact oracle bytes, the pipelined executors
+  keep the committed jaxpr ppermute budgets, the compiled HLO stays
+  collective-permute-only, and every step of the fused program carries its
+  ``round<r>`` / ``local<i>`` scope.
 
 Property tests are hypothesis-driven when hypothesis is installed
 (tests/hyputil.py); the exhaustive parametrized sweeps below double as the
@@ -26,6 +26,7 @@ seeded-random fallback and always run.
 
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -278,7 +279,7 @@ def test_pipeline_registered_and_declines_non_prologue_irs():
 
 
 # ---------------------------------------------------------------------------
-# subprocess: the three kernel lowerings on a real forced-host mesh
+# subprocess: the two LocalOp lowerings on a real forced-host mesh
 # ---------------------------------------------------------------------------
 
 
@@ -299,11 +300,11 @@ def run_child(code: str, devices: int = 8) -> str:
 
 
 def test_kernel_modes_differential_on_mesh():
-    """All KERNEL_MODES × {"", "pipeline"} on the 8-device mesh: ps
+    """Both LocalOp lowerings × {"", "pipeline"} on the 8-device mesh: ps
     (both fields, Lagrange + random generators, odd payload), multilevel,
     hierarchical and butterfly — every lowering produces the exact oracle
-    bytes. pallas runs in interpret mode on CPU (same kernels the TPU path
-    jits)."""
+    bytes. The test pins the lowering by replacing ``_lowering``; pallas
+    then runs in interpret mode on CPU (same kernels the TPU path jits)."""
     out = run_child(
         """
         import numpy as np, jax, jax.numpy as jnp
@@ -312,9 +313,15 @@ def test_kernel_modes_differential_on_mesh():
         from repro.core.matrices import (
             distinct_points, lagrange_matrix, random_matrix, random_vector)
         from repro.core.prepare_shoot import encode_oracle
+        from repro.dist import collectives
         from repro.dist.collectives import (
-            KERNEL_MODES, butterfly_jit, hierarchical_encode_jit,
-            multilevel_encode_jit, ps_encode_jit)
+            butterfly_jit, hierarchical_encode_jit, multilevel_encode_jit,
+            ps_encode_jit)
+
+        LOWERINGS = ("fused", "pallas")
+
+        def pin(lowering):  # the test seam: what a mesh platform maps to
+            collectives._lowering = lambda platform: lowering
 
         K = 8
         mesh1 = make_mesh((8,), ("enc",))
@@ -332,13 +339,15 @@ def test_kernel_modes_differential_on_mesh():
             xs = jnp.asarray(x.astype(np.uint32))
             for name, A in gens.items():
                 want = encode_oracle(x, A, q)
-                for kern in KERNEL_MODES:
+                for kern in LOWERINGS:
+                    pin(kern)
                     for pipe in ("", "pipeline"):
                         fn, _ = ps_encode_jit(mesh1, "enc", np.asarray(A),
-                                              p=1, q=q, kernels=kern,
-                                              pipeline=pipe)
+                                              p=1, q=q, pipeline=pipe)
                         got = np.asarray(fn(xs), dtype=np.uint64)
                         assert np.array_equal(got, want), (q, name, kern, pipe)
+                        kernel_ran = "pallas_call" in str(jax.make_jaxpr(fn)(xs))
+                        assert kernel_ran == (kern == "pallas"), (kern, pipe)
         # multilevel + hierarchical: fused/pallas with the pipeline applied
         f = Field(M31)
         A = random_matrix(f, K, seed=4)
@@ -346,15 +355,15 @@ def test_kernel_modes_differential_on_mesh():
         xs = jnp.asarray(x.astype(np.uint32))
         want = encode_oracle(x, A, M31)
         for kern, pipe in [("fused", "pipeline"), ("pallas", "pipeline"),
-                           ("jnp", "pipeline"), ("fused", "")]:
+                           ("fused", "")]:
+            pin(kern)
             fn, _ = multilevel_encode_jit(
                 mesh3, ("pod", "slice", "chip"), np.asarray(A), p=1,
-                kernels=kern, pipeline=pipe)
+                pipeline=pipe)
             assert np.array_equal(np.asarray(fn(xs), dtype=np.uint64), want), (
                 "ml", kern, pipe)
             fn, _ = hierarchical_encode_jit(
-                mesh2, "inter", "intra", np.asarray(A), p=1,
-                kernels=kern, pipeline=pipe)
+                mesh2, "inter", "intra", np.asarray(A), p=1, pipeline=pipe)
             assert np.array_equal(np.asarray(fn(xs), dtype=np.uint64), want), (
                 "hier", kern, pipe)
         # butterfly (NTT twiddles hit the butterfly_mac lowering)
@@ -363,9 +372,11 @@ def test_kernel_modes_differential_on_mesh():
         xb = random_vector(fq, (K, 5), seed=6)
         xbs = jnp.asarray(xb.astype(np.uint32))
         wantb = encode_oracle(xb, butterfly_target_matrix(fq, K, 2), NTT)
-        for kern in KERNEL_MODES:
-            fnb, _ = butterfly_jit(mesh1, "enc", q=NTT, kernels=kern)
+        for kern in LOWERINGS:
+            pin(kern)
+            fnb, _ = butterfly_jit(mesh1, "enc", q=NTT)
             assert np.array_equal(np.asarray(fnb(xbs), dtype=np.uint64), wantb), kern
+            assert ("pallas_call" in str(jax.make_jaxpr(fnb)(xbs))) == (kern == "pallas")
         print("kernel modes ok")
         """
     )
@@ -415,57 +426,94 @@ def test_pipelined_budget_regression_and_hlo():
     assert "pipelined budgets ok" in out
 
 
-def test_pipelined_traced_spans_show_overlap(tmp_path):
-    """The traced pipelined 2×2×2 multilevel run: round spans carry
-    overlap=True + overlap_out_slots (PR 7's telemetry sees the hidden
-    contraction), predicted_us stays present, and the exported Chrome trace
-    passes tools/check_trace.py."""
-    trace = tmp_path / "pipelined.trace.json"
-    out = run_child(
-        f"""
-        import numpy as np, jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh
-        from repro.core.field import M31, Field
-        from repro.core.matrices import random_matrix, random_vector
-        from repro.dist.collectives import ir_encode_jit, _apply_pipeline
-        from repro.obs import Tracer
-        from repro.obs.export import write_chrome_trace
-        from repro.topo import Hierarchy, plan_multilevel
+#: the builds whose fused program the scope test reads: (id, builder source)
+_SCOPE_BUILDS = {
+    "ps-p1": 'ps_encode_jit(mesh1, "enc", A, p=1)',
+    "ps-p2": 'ps_encode_jit(mesh1, "enc", A, p=2)',
+    "ps-p1-pipeline": 'ps_encode_jit(mesh1, "enc", A, p=1, pipeline="pipeline")',
+    "hierarchical-4x2": 'hierarchical_encode_jit(mesh2, "inter", "intra", A, p=1)',
+    "multilevel-2x2x2": 'multilevel_encode_jit(mesh3, AXES3, A, p=1)',
+    "multilevel-2x2x2-pipeline": (
+        'multilevel_encode_jit(mesh3, AXES3, A, p=1, pipeline="pipeline")'
+    ),
+    "butterfly-ntt": 'butterfly_jit(mesh1, "enc", q=NTT)',
+}
 
-        K = 8
-        f = Field(M31)
-        A = np.asarray(random_matrix(f, K, seed=0))
-        ir = _apply_pipeline(plan_multilevel(K, 1, (2, 2, 2)).to_ir(A), "pipeline")
-        mesh = make_mesh((2, 2, 2), ("pod", "slice", "chip"))
-        x = jnp.asarray(random_vector(f, (K, 32), seed=1).astype(np.uint32))
-        tracer = Tracer()
-        fn = ir_encode_jit(mesh, ("pod", "slice", "chip"), ir,
-                           tracer=tracer, topo=Hierarchy(levels=(2, 2, 2)))
-        from repro.core.prepare_shoot import encode_oracle
-        got = np.asarray(fn(x), dtype=np.uint64)
-        assert np.array_equal(got, encode_oracle(
-            np.asarray(x, dtype=np.uint64), A, M31))
-        comm = [s for s in tracer.spans if "comm_round" in s.attrs]
-        assert len(comm) == 3, len(comm)
-        overlapped = [s for s in comm if s.attrs.get("overlap")]
-        assert overlapped, "no round span carries the overlap annotation"
-        for s in overlapped:
-            assert s.attrs["overlap_out_slots"] > 0
-        for s in comm:
-            assert "predicted_us" in s.attrs
-        write_chrome_trace(tracer.spans, {str(trace)!r})
-        print("overlap spans ok")
-        """
+
+@pytest.fixture(scope="module")
+def scoped_programs(tmp_path_factory):
+    """Lower and compile every build of ``_SCOPE_BUILDS`` once on the forced
+    8-device mesh; per build, the IR's CommRound and LocalOp counts, the
+    lowered text with its debug locations and the compiled HLO text."""
+    out_dir = tmp_path_factory.mktemp("scopes")
+    builds = ",\n".join(f"    {k!r}: lambda: {v}" for k, v in _SCOPE_BUILDS.items())
+    run_child(
+        f"""
+import json, os
+import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
+from repro.core.field import M31, NTT, Field
+from repro.core.ir import CommRound, LocalOp
+from repro.core.matrices import random_matrix
+from repro.dist.collectives import (
+    _apply_pipeline, butterfly_jit, hierarchical_encode_jit,
+    multilevel_encode_jit, ps_encode_jit)
+
+A = np.asarray(random_matrix(Field(M31), 8, seed=0))
+AXES3 = ("pod", "slice", "chip")
+mesh1 = make_mesh((8,), ("enc",))
+mesh2 = make_mesh((4, 2), ("inter", "intra"))
+mesh3 = make_mesh((2, 2, 2), AXES3)
+BUILDS = {{
+{builds}
+}}
+PIPES = {{k: "pipeline" if k.endswith("-pipeline") else "" for k in BUILDS}}
+for key, build in BUILDS.items():
+    fn, plan = build()
+    ir = plan.to_ir() if key.startswith("butterfly") else plan.to_ir(A, q=M31)
+    ir = _apply_pipeline(ir, PIPES[key])
+    lowered = fn.lower(jax.ShapeDtypeStruct((8, 16), jnp.uint32))
+    rec = {{
+        "rounds": sum(isinstance(s, CommRound) for s in ir.steps),
+        "locals": sum(isinstance(s, LocalOp) for s in ir.steps),
+        "lowered": lowered.as_text(debug_info=True),
+        "compiled": lowered.compile().as_text(),
+    }}
+    with open(os.path.join({str(out_dir)!r}, key + ".json"), "w") as fh:
+        json.dump(rec, fh)
+print("scoped programs written")
+"""
     )
-    assert "overlap spans ok" in out
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "check_trace.py"), str(trace)],
-        capture_output=True,
-        text=True,
-        timeout=120,
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-    data = json.loads(trace.read_text())
-    assert any(
-        ev.get("args", {}).get("overlap") for ev in data["traceEvents"]
-    ), "exported trace lost the overlap attr"
+    return {
+        key: json.loads((out_dir / f"{key}.json").read_text())
+        for key in _SCOPE_BUILDS
+    }
+
+
+@pytest.mark.parametrize("build", list(_SCOPE_BUILDS))
+def test_fused_program_steps_carry_their_scopes(scoped_programs, build):
+    """Every step of the one fused program is attributable in a device
+    profile: the lowered text names every ``round<r>`` and every
+    ``local<i>`` of the IR (the compiler may fold a LocalOp's ops away, so
+    the locals are read before compilation), and in the compiled HLO every
+    collective-permute sits under a ``round<r>`` scope, each round owning at
+    least one."""
+    rec = scoped_programs[build]
+    assert rec["rounds"] >= 1 and rec["locals"] >= 1, rec["rounds"]
+    want = {f"round{r}" for r in range(rec["rounds"])} | {
+        f"local{i}" for i in range(rec["locals"])
+    }
+    named = set(re.findall(r'["/]((?:round|local)\d+)/', rec["lowered"]))
+    assert named == want, (build, sorted(named ^ want))
+
+    permutes = [
+        line for line in rec["compiled"].splitlines()
+        if re.search(r"\scollective-permute(-start)?\(", line)
+    ]
+    assert permutes, build
+    owners = []
+    for line in permutes:
+        m = re.search(r'op_name="[^"]*/round(\d+)/', line)
+        assert m, (build, line)
+        owners.append(int(m.group(1)))
+    assert set(owners) == set(range(rec["rounds"])), (build, sorted(set(owners)))

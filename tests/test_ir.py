@@ -531,21 +531,6 @@ def test_fit_level_costs_recovers_planted_alpha_beta():
         fit_level_costs(samples[:2], n_levels=3)
 
 
-def test_bench_topology_calibration_block_roundtrips():
-    """If the benchmark has produced results/BENCH_topology.json with a
-    calibration block, the samples feed fit_level_costs directly."""
-    import json
-
-    path = os.path.join(REPO, "results", "BENCH_topology.json")
-    if not os.path.exists(path):
-        pytest.skip("benchmark results not present")
-    rec = json.load(open(path))
-    if "calibration" not in rec:
-        pytest.skip("old-format benchmark results")
-    fitted = fit_level_costs(rec["calibration"]["samples"], n_levels=3)
-    assert len(fitted) == 3 and all(c.alpha > 0 and c.beta > 0 for c in fitted)
-
-
 # ---------------------------------------------------------------------------
 # generic executor on a torus mesh (subprocess; the CI torus-mesh step)
 # ---------------------------------------------------------------------------

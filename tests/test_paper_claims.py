@@ -28,7 +28,7 @@ from repro.core.simulator import (
     simulate_prepare_shoot,
 )
 
-KS = [2, 3, 4, 5, 7, 8, 9, 12, 16, 17, 25, 31, 32, 64, 65, 100]
+KS = [2, 3, 4, 5, 7, 8, 9, 12, 16, 17, 25, 31, 32, 64, 65, 100, 128, 256, 512]
 PS = [1, 2, 3]
 
 
@@ -56,7 +56,9 @@ def test_prepare_shoot_correct_and_costs(K, p):
     assert stats.C2 >= math.floor(bounds.lemma2_c2_lower(K, p)) - 1
 
 
-@pytest.mark.parametrize("K,p", [(2, 1), (4, 1), (8, 1), (16, 1), (32, 1), (3, 2), (9, 2), (16, 3)])
+@pytest.mark.parametrize(
+    "K,p", [(2, 1), (4, 1), (8, 1), (16, 1), (32, 1), (64, 1), (256, 1), (3, 2), (9, 2), (16, 3)]
+)
 def test_butterfly_dft_exact_and_strictly_optimal(K, p):
     """Theorem 2: C1 = C2 = log_{p+1}K; computes the (rev-row) DFT matrix."""
     q = NTT if (NTT - 1) % K == 0 and K % (p + 1) == 0 else M31
@@ -97,6 +99,8 @@ def test_butterfly_inverse_roundtrip(K, p):
         (20, 1, NTT),  # M=5, H=2
         (18, 2, M31),  # M=2, H=2 (radix 3 over M31: 3^2 | q-1)
         (24, 1, NTT),  # M=3, H=3
+        (48, 1, NTT),  # M=3, H=4
+        (96, 1, NTT),  # M=3, H=5
         (7, 1, NTT),  # H=0 → degrades to pure universal draw (Remark 5)
     ],
 )

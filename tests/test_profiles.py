@@ -59,7 +59,7 @@ def test_resolve_profile_picks_hierarchical_from_mesh_topology():
     from repro.launch.mesh import production_topology
     from repro.launch.profiles import resolve_profile
 
-    prof = resolve_profile(multi_pod=True, calibration=False)
+    prof = resolve_profile(multi_pod=True)
     # compute-aware pricing may pick the pipelined rewrite of the same
     # family; the base algorithm and plan are the contract here
     assert prof.algorithm.split("+")[0] == "multilevel"
@@ -67,7 +67,7 @@ def test_resolve_profile_picks_hierarchical_from_mesh_topology():
     assert prof.topology.levels == production_topology(multi_pod=True).levels
     assert prof.tune.chosen.plan is prof.plan
 
-    single = resolve_profile(multi_pod=False, calibration=False)
+    single = resolve_profile(multi_pod=False)
     assert single.algorithm.split("+")[0] == "hierarchical"
     assert single.levels == (4, 4)
 
@@ -85,8 +85,7 @@ def test_resolve_profile_from_live_mesh_shape():
     axes = ("pod", "slice", "chip")
     assert mesh_encode_levels(mesh, axes) == (2, 2, 2)
     assert topology_for_mesh(mesh, axes).levels == (2, 2, 2)
-    prof = resolve_profile(mesh=mesh, axes=axes, payload_bytes=65536,
-                           calibration=False)
+    prof = resolve_profile(mesh=mesh, axes=axes, payload_bytes=65536)
     # at 64k payloads the compute-aware price makes the pipelined rewrite of
     # the same schedule strictly cheaper, so accept an optional +<pipeline>
     # suffix — the base family and the plan factorization are the contract
@@ -98,17 +97,17 @@ def test_resolve_profile_from_live_mesh_shape():
 
 def test_resolve_profile_measured_override():
     """Wall-clock calibration flows through: forcing every algorithm but
-    prepare-shoot to be slow flips the choice (the BENCH_topology.json
-    measured_s feedback path)."""
+    prepare-shoot to be slow flips the choice (the measured-override
+    path)."""
     from repro.launch.profiles import resolve_profile
 
-    base = resolve_profile(multi_pod=True, calibration=False)
+    base = resolve_profile(multi_pod=True)
     slow = {
         c.algorithm: 1.0
         for c in base.tune.candidates
         if c.algorithm != "prepare-shoot"
     }
-    forced = resolve_profile(multi_pod=True, calibration=False,
+    forced = resolve_profile(multi_pod=True,
                              measured={**slow, "prepare-shoot": 1e-9})
     assert forced.algorithm == "prepare-shoot"
 
@@ -133,55 +132,13 @@ def test_resolve_profile_threads_generator_kind():
     from repro.core.field import NTT
     from repro.launch.profiles import resolve_profile
 
-    default = resolve_profile(multi_pod=False, calibration=False)
+    default = resolve_profile(multi_pod=False)
     names = {c.base_algorithm for c in default.tune.candidates}
     assert "multilevel-dft" not in names and "draw-loose" not in names
 
-    dft = resolve_profile(
-        multi_pod=False, q=NTT, generator="dft", calibration=False
-    )
+    dft = resolve_profile(multi_pod=False, q=NTT, generator="dft")
     dft_names = {c.base_algorithm for c in dft.tune.candidates}
     assert "hierarchical-dft" in dft_names or "multilevel-dft" in dft_names
-
-
-def test_resolve_profile_prices_with_fitted_calibration(tmp_path):
-    """Acceptance: when persisted calibration rows exist, resolve_profile
-    loads them (topo.calibrate.load_fitted_costs), replaces the hierarchy's
-    level costs, exposes them on EncodeProfile.fitted_costs, and the
-    candidate table's prices visibly reflect the fitted α/β."""
-    import json
-
-    from repro.launch.profiles import resolve_profile
-    from repro.topo import LinkCost, load_fitted_costs
-
-    # absurdly slow fitted constants so the repricing is unmistakable
-    rows = [
-        {"level": 0, "alpha_s": 0.5, "beta_s_per_elem": 1e-6},
-        {"level": 1, "alpha_s": 2.0, "beta_s_per_elem": 1e-5},
-    ]
-    path = tmp_path / "BENCH_topology.json"
-    path.write_text(json.dumps({"calibration": {"fitted_level_costs": rows}}))
-
-    fitted = load_fitted_costs(str(path))
-    assert fitted == (LinkCost(0.5, 1e-6), LinkCost(2.0, 1e-5))
-    assert load_fitted_costs(str(tmp_path / "missing.json")) is None
-
-    # multi_pod=False → Hierarchy((4, 4)): 2 levels, exact match with rows
-    prof = resolve_profile(multi_pod=False, calibration=str(path))
-    assert prof.fitted_costs == fitted
-    assert tuple(prof.topology.costs) == fitted
-    assert prof.tune.chosen.predicted_time > 1.0  # α alone is ≥ 0.5 s/round
-
-    base = resolve_profile(multi_pod=False, calibration=False)
-    assert base.fitted_costs is None
-    assert base.tune.chosen.predicted_time < 1.0
-
-    # level-count mismatch: fitted endpoints re-interpolated to 3 levels
-    deep = resolve_profile(multi_pod=True, calibration=str(path))
-    assert deep.fitted_costs is not None
-    assert len(deep.fitted_costs) == len(deep.topology.levels) == 3
-    assert deep.fitted_costs[0] == fitted[0]
-    assert deep.fitted_costs[-1] == fitted[-1]
 
 
 def test_opt_profile_smoke_compiles_1dev(mesh):

@@ -11,12 +11,9 @@ Acceptance:
   8-forced-host-device (2×4 data×model) mesh variant in a subprocess.
 * The fixed-batch ``Engine`` reports generated-tokens-only throughput and
   per-sequence EOS-trimmed ``lengths``.
-* ``tools/check_trace.py --kind serve`` gates the harness record's schema
-  and semantic invariants (p50 ≤ p99, occupancy ∈ [0, 1], compile bound).
 """
 
 import functools
-import json
 import os
 import subprocess
 import sys
@@ -416,65 +413,3 @@ def test_continuous_metrics_and_report():
     # re-serving reuses the compiled graphs: no new prefill compiles
     eng.serve(reqs, greedy=True, sync_every=2)
     assert eng.prefill_compiles == rep.prefill_compiles
-
-
-def _serve_record(**edits):
-    eng = {
-        "tokens_per_s": 100.0, "ttft_ms": {"p50": 1.0, "p99": 2.0},
-        "e2e_ms": {"p50": 3.0, "p99": 4.0}, "n_requests": 4, "wall_s": 0.5,
-    }
-    rec = {
-        "workload": {"n_requests": 4, "rate_rps": 50.0, "seed": 0},
-        "n_slots": 2,
-        "buckets": [8, 16],
-        "engines": {
-            "fixed_batch": dict(eng),
-            "continuous": {
-                **eng, "slot_occupancy": 0.8, "prefill_compiles": 2,
-                "decode_steps": 40,
-            },
-        },
-    }
-    for dotted, v in edits.items():
-        cur = rec
-        parts = dotted.split(".")
-        for p in parts[:-1]:
-            cur = cur[p]
-        cur[parts[-1]] = v
-    return rec
-
-
-def test_check_trace_serve_kind():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import check_trace
-
-        assert check_trace.check_serve(_serve_record()) == []
-        # p50 > p99
-        bad = _serve_record(**{"engines.continuous.ttft_ms": {"p50": 9.0, "p99": 2.0}})
-        assert check_trace.check_serve(bad)
-        # occupancy outside [0, 1]
-        bad = _serve_record(**{"engines.continuous.slot_occupancy": 1.5})
-        assert check_trace.check_serve(bad)
-        # unbounded recompiles
-        bad = _serve_record(**{"engines.continuous.prefill_compiles": 3})
-        assert check_trace.check_serve(bad)
-        # missing engine row
-        bad = _serve_record()
-        del bad["engines"]["fixed_batch"]
-        assert check_trace.check_serve(bad)
-    finally:
-        sys.path.pop(0)
-
-
-def test_check_trace_serve_cli(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import check_trace
-
-        path = tmp_path / "BENCH_serve.json"
-        path.write_text(json.dumps(_serve_record()))
-        assert check_trace.main([str(path)]) == 0  # auto-detected via engines
-        assert check_trace.main(["--kind", "serve", str(path)]) == 0
-    finally:
-        sys.path.pop(0)

@@ -97,7 +97,7 @@ def test_butterfly_mac_compiles(one_chip, radix):
 
 
 def test_ps_encode_compiles_permute_only(topo):
-    """K=4 universal encode on the four described chips: kernels=None
+    """K=4 universal encode on the four described chips: the lowering
     follows the mesh's platform to the compiled Pallas kernels, and the
     rounds lower to collective-permutes only."""
     from repro.dist import ps_encode_jit
